@@ -35,6 +35,7 @@ import numpy as np
 # single-core hosts deadlock the pure_callback serving path (see conftest);
 # arm the XLA thread-pool workaround before any scenario builds a CPU client
 from repro.backend import ensure_host_callback_capacity
+from repro.launch.compile_cache import enable_compile_cache
 
 ensure_host_callback_capacity()
 
@@ -1050,6 +1051,7 @@ def main() -> None:
                          "escapes, zero corrupted completions, and peak p99 "
                          "TTFT within the SLO and no worse than static")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.json_out and not args.only:
         ap.error("--json-out requires --only (it names a single artifact)")
     _OUT["dir"] = args.out_dir

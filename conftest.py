@@ -1,71 +1,18 @@
 """Repo-wide pytest config.
 
-Two pieces:
-
-* Single-core CI hosts deadlock the ``pure_callback`` serving path (XLA's
-  CPU client gets a one-thread pool there, and a host callback waiting on a
-  jax array starves the enclosing jit'd step).
-  ``ensure_host_callback_capacity`` injects
-  ``--xla_force_host_platform_device_count=2`` into ``XLA_FLAGS`` before any
-  test creates the CPU client, which gives the pool a second thread and
-  makes the emulated/guarded serving tests runnable everywhere.
-
-* The container does not ship ``hypothesis``; four test modules use it for
-  property tests.  Rather than losing those modules' example-based tests to
-  a collection error, install a minimal shim that skips ``@given`` tests
-  when the real library is unavailable.
+Single-core CI hosts deadlock the ``pure_callback`` serving path (XLA's CPU
+client gets a one-thread pool there, and a host callback waiting on a jax
+array starves the enclosing jit'd step).  ``ensure_host_callback_capacity``
+injects ``--xla_force_host_platform_device_count=2`` into ``XLA_FLAGS``
+before any test creates the CPU client, which gives the pool a second thread
+and makes the emulated/guarded serving tests runnable everywhere.
 """
 
 import os
 import sys
-import types
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "src"))
 
 from repro.backend.base import ensure_host_callback_capacity  # noqa: E402
 
 ensure_host_callback_capacity()
-
-try:  # pragma: no cover - exercised only where hypothesis exists
-    import hypothesis  # noqa: F401
-except ImportError:
-    import pytest
-
-    def _strategy(*args, **kwargs):
-        return None
-
-    st = types.ModuleType("hypothesis.strategies")
-    for _name in ("floats", "integers", "lists", "booleans", "sampled_from",
-                  "just", "tuples", "text", "none", "one_of"):
-        setattr(st, _name, _strategy)
-
-    def _composite(fn):
-        def build(*args, **kwargs):
-            return None
-        return build
-
-    st.composite = _composite
-
-    hyp = types.ModuleType("hypothesis")
-
-    def given(*args, **kwargs):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed; property test skipped")(fn)
-        return deco
-
-    def settings(*args, **kwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    def assume(condition):
-        return bool(condition)
-
-    hyp.given = given
-    hyp.settings = settings
-    hyp.assume = assume
-    hyp.strategies = st
-    hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st

@@ -47,7 +47,7 @@ MAX_LEN = 32
 
 def _sub_jaxprs(params: Dict[str, Any]):
     """Yield every Jaxpr/ClosedJaxpr buried in an eqn's params."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     def visit(v):
         if isinstance(v, (jcore.Jaxpr, jcore.ClosedJaxpr)):
@@ -60,7 +60,7 @@ def _sub_jaxprs(params: Dict[str, Any]):
 
 
 def _walk(jaxpr, counts: Dict[str, Any], weight: int = 1) -> None:
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr

@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .tuning import resolve_interpret
 
@@ -29,7 +30,7 @@ def _quant_rows(x, levels: float):
 def _kernel(a_ref, bt_ref, tier_ref, out_ref):
     a = a_ref[...].astype(jnp.float32)
     bt = bt_ref[...].astype(jnp.float32)
-    tier = tier_ref[0, 0]
+    tier = tier_ref[pl.program_id(0), pl.program_id(1)]
     f32 = jnp.dot(a, bt.T, preferred_element_type=jnp.float32)
     qa8, sa8 = _quant_rows(a, 127.0)
     qb8, sb8 = _quant_rows(bt, 127.0)
@@ -53,7 +54,9 @@ def _precision_island_call(a, b, tiers, *, block_m: int, block_n: int,
         in_specs=[
             pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            # the whole tier map sits in scalar memory: a (1, 1) vector
+            # block breaks the TPU's (8, 128) tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),

@@ -24,8 +24,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .tuning import resolve_interpret, select_blocks, sequential_grid
+
+# Per-tile scalars (flag, rel, the flag count) live whole in scalar memory:
+# a (1, 1) vector block breaks the TPU's (8, 128) tiling rule.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _quant_rows(x):
@@ -56,8 +61,8 @@ def _kernel(a_ref, bt_ref, out_ref, flag_ref, rel_ref, count_ref, *,
     fired = rel > tol
     # fused epilogue: correction + flag + running flag reduction in one pass
     out_ref[...] = jnp.where(fired, shadow, main)
-    flag_ref[0, 0] = fired.astype(jnp.int32)
-    rel_ref[0, 0] = rel
+    flag_ref[i, j] = fired.astype(jnp.int32)
+    rel_ref[i, j] = rel
     count_ref[0, 0] += fired.astype(jnp.int32)
 
 
@@ -80,9 +85,9 @@ def _razor_matmul_call(a, b, *, tol: float, block_m: int, block_n: int,
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            _SMEM,
+            _SMEM,
+            _SMEM,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),

@@ -23,42 +23,50 @@ from .tuning import assert_divides, resolve_interpret, select_chunk
 EXP_CLAMP = 30.0
 
 
-def _kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, s0_ref,
+def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, s0_ref,
             y_ref, sout_ref, state, *, n_chunks: int):
-    c_idx = pl.program_id(1)
+    i, c_idx = pl.program_id(0), pl.program_id(1)
 
     @pl.when(c_idx == 0)
     def _init():
         state[...] = s0_ref[0]
 
     x = x_ref[0].astype(jnp.float32)          # (ch, p)
-    dt = dt_ref[0].astype(jnp.float32)        # (ch,)
+    dt = dt_ref[0].astype(jnp.float32)        # (ch, 1)
     B = b_ref[0].astype(jnp.float32)          # (ch, n)
     C = c_ref[0].astype(jnp.float32)          # (ch, n)
-    a = -jnp.exp(alog_ref[0])                 # scalar
-    D = d_ref[0]                              # scalar
+    a = a_ref[i]                              # scalar -exp(A_log)
+    D = d_ref[i]                              # scalar
     S = state[...]                            # (n, p)
 
-    da = dt * a                               # (ch,) log decay, <= 0
-    cum = jnp.cumsum(da)                      # inclusive
-    scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32)  # (ch, ch)
-    decay = jnp.exp(jnp.clip(cum[:, None] - cum[None, :], -EXP_CLAMP,
-                             EXP_CLAMP))
     ch = x.shape[0]
-    mask = jnp.tril(jnp.ones((ch, ch), jnp.float32))              # inclusive
-    W = scores * decay * mask
-    xdt = x * dt[:, None]
+    row = jax.lax.broadcasted_iota(jnp.int32, (ch, ch), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ch, ch), 1)
+    da = dt * a                               # (ch, 1) log decay, <= 0
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # matmul, at full f32 precision
+    cum = jnp.dot((row >= col).astype(jnp.float32), da,
+                  preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)         # (ch, 1)
+    # the same prefix sums as a row: pick the diagonal of the broadcast
+    cum_row = jnp.sum(jnp.where(row == col, cum, 0.0), axis=0,
+                      keepdims=True)                           # (1, ch)
+    scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32)  # (ch, ch)
+    decay = jnp.exp(jnp.clip(cum - cum_row, -EXP_CLAMP, EXP_CLAMP))
+    W = jnp.where(row >= col, scores * decay, 0.0)             # inclusive
+    xdt = x * dt
     y = jnp.dot(W, xdt, preferred_element_type=jnp.float32)
     # inter-chunk: C_t . S_in * exp(cum_t)
     y = y + jnp.dot(C, S, preferred_element_type=jnp.float32) \
-        * jnp.exp(jnp.clip(cum, -EXP_CLAMP, 0.0))[:, None]
+        * jnp.exp(jnp.clip(cum, -EXP_CLAMP, 0.0))
     y = y + D * x
     y_ref[0] = y
 
     # state: S' = S * exp(cum_end) + sum_s exp(cum_end - cum_s) B_s (x) xdt_s
-    tail = jnp.exp(jnp.clip(cum[-1] - cum, -EXP_CLAMP, EXP_CLAMP))
-    B_tail = B * tail[:, None]
-    state[...] = (S * jnp.exp(jnp.clip(cum[-1], -EXP_CLAMP, 0.0))
+    cum_end = cum[ch - 1, 0]                                   # scalar
+    tail = jnp.exp(jnp.clip(cum_end - cum, -EXP_CLAMP, EXP_CLAMP))
+    B_tail = B * tail
+    state[...] = (S * jnp.exp(jnp.clip(cum_end, -EXP_CLAMP, 0.0))
                   + jnp.dot(B_tail.T, xdt, preferred_element_type=jnp.float32))
 
     @pl.when(c_idx == n_chunks - 1)
@@ -90,12 +98,12 @@ def _ssd_chunk_call(x: jax.Array, dt: jax.Array, A_log: jax.Array,
     bh = b * h
 
     xf = jnp.moveaxis(x.astype(jnp.float32), 2, 1).reshape(bh, s, p)
-    dtf = jnp.moveaxis(dt.astype(jnp.float32), 2, 1).reshape(bh, s)
+    dtf = jnp.moveaxis(dt.astype(jnp.float32), 2, 1).reshape(bh, s, 1)
     Bf = jnp.repeat(B.astype(jnp.float32), h, axis=0).reshape(b, h, s, n) \
         .reshape(bh, s, n)
     Cf = jnp.repeat(C.astype(jnp.float32), h, axis=0).reshape(b, h, s, n) \
         .reshape(bh, s, n)
-    af = jnp.tile(A_log.astype(jnp.float32), b)            # (bh,)
+    af = jnp.tile(-jnp.exp(A_log.astype(jnp.float32)), b)  # (bh,)
     df = jnp.tile(D.astype(jnp.float32), b)
     sf = state.reshape(bh, n, p).astype(jnp.float32)
 
@@ -104,11 +112,13 @@ def _ssd_chunk_call(x: jax.Array, dt: jax.Array, A_log: jax.Array,
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, chunk), lambda i, c: (i, c)),
+            pl.BlockSpec((1, chunk, 1), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1,), lambda i, c: (i,)),
-            pl.BlockSpec((1,), lambda i, c: (i,)),
+            # per-head scalars sit whole in scalar memory: a (1,) vector
+            # block breaks the TPU's (8, 128) tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n, p), lambda i, c: (i, 0, 0)),
         ],
         out_specs=[

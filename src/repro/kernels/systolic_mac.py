@@ -29,6 +29,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .tuning import resolve_interpret, select_blocks, sequential_grid
 
+# Per-tile scalars (rails, flags, the flag count) live whole in scalar
+# memory: a (1, 1) vector block breaks the TPU's (8, 128) tiling rule.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _kernel(a_ref, b_ref, vmap_ref, vsafe_ref, out_ref, flag_ref, count_ref,
             acc_ref, *, keep_bits: int, n_k: int):
@@ -49,13 +53,13 @@ def _kernel(a_ref, b_ref, vmap_ref, vsafe_ref, out_ref, flag_ref, count_ref,
     @pl.when(k == n_k - 1)
     def _finish():
         acc = acc_ref[...]
-        fail = vmap_ref[0, 0] < vsafe_ref[0, 0]
+        fail = vmap_ref[i, j] < vsafe_ref[i, j]
         # mantissa truncation = low accumulator bits missing the clock edge
         bits = jax.lax.bitcast_convert_type(acc, jnp.uint32)
         mask = jnp.uint32(0xFFFFFFFF) << jnp.uint32(23 - keep_bits)
         corrupted = jax.lax.bitcast_convert_type(bits & mask, jnp.float32)
         out_ref[...] = jnp.where(fail, corrupted, acc)
-        flag_ref[0, 0] = fail.astype(jnp.int32)
+        flag_ref[i, j] = fail.astype(jnp.int32)
         # fused Razor flag reduction: running total over all (i, j) tiles
         count_ref[0, 0] += fail.astype(jnp.int32)
 
@@ -77,13 +81,13 @@ def _systolic_mac_call(a, b, v_map, v_safe, *, block_m: int, block_n: int,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),

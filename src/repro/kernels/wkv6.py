@@ -37,32 +37,44 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     w_log = w_ref[0].astype(jnp.float32)      # (ch, p), log decay <= 0
-    u = u_ref[0].astype(jnp.float32)          # (p,)
+    u = u_ref[0].astype(jnp.float32)          # (1, p)
     S = state[...]                            # (p, p)
 
-    ch = r.shape[0]
-    lw = jnp.cumsum(w_log, axis=0)            # inclusive
-    lw_prev = jnp.concatenate([jnp.zeros_like(lw[:1]), lw[:-1]], axis=0)
+    ch, p = r.shape
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # matmul, at full f32 precision
+    row = jax.lax.broadcasted_iota(jnp.int32, (ch, ch), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ch, ch), 1)
+    lw = jnp.dot((row >= col).astype(jnp.float32), w_log,
+                 preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)          # inclusive
+    lw_prev = lw - w_log                                       # exclusive
     # centre exponents at half the chunk's total decay so exp() stays in
     # f32 range for any chunk length (the A entries are products
     # exp(lw_prev_t - m) * exp(m - lw_s) = exp(lw_prev_t - lw_s) <= 1)
-    m = 0.5 * lw[-1:]
+    lw_end = lw[ch - 1:]                                       # (1, p)
+    m = 0.5 * lw_end
     rr = r * jnp.exp(jnp.clip(lw_prev - m, -EXP_CLAMP, EXP_CLAMP))
     kk = k * jnp.exp(jnp.clip(m - lw, -EXP_CLAMP, EXP_CLAMP))
     A = jnp.dot(rr, kk.T, preferred_element_type=jnp.float32)   # (ch, ch)
-    mask = jnp.tril(jnp.ones((ch, ch), jnp.float32), k=-1)
-    A = A * mask
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)                 # (ch,)
+    A = jnp.where(row > col, A, 0.0)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)          # (ch, 1)
     y = jnp.dot(A, v, preferred_element_type=jnp.float32)
-    y = y + diag[:, None] * v
+    y = y + diag * v
     # inter-chunk from carried state (exp(lw_prev) <= 1: no centring needed)
     r_state = r * jnp.exp(jnp.clip(lw_prev, -EXP_CLAMP, 0.0))
     y = y + jnp.dot(r_state, S, preferred_element_type=jnp.float32)
 
     # state update: S' = diag(prod w) S + sum_s (k_s * decay_to_end) v_s^T
-    tail = jnp.exp(jnp.clip(lw[-1:] - lw, -EXP_CLAMP, EXP_CLAMP))
+    tail = jnp.exp(jnp.clip(lw_end - lw, -EXP_CLAMP, EXP_CLAMP))
     k_tail = k * tail
-    S_new = (S * jnp.exp(jnp.clip(lw[-1], -EXP_CLAMP, 0.0))[:, None]
+    # the per-row decay as a column: pick the diagonal of a broadcast row
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (p, p), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (p, p), 1))
+    decay_col = jnp.sum(jnp.where(eye, jnp.exp(jnp.clip(lw_end, -EXP_CLAMP,
+                                                         0.0)), 0.0),
+                        axis=1, keepdims=True)                 # (p, 1)
+    S_new = (S * decay_col
              + jnp.dot(k_tail.T, v, preferred_element_type=jnp.float32))
     state[...] = S_new
     y_ref[0] = y
@@ -100,7 +112,7 @@ def _wkv6_call(r: jax.Array, k: jax.Array, v: jax.Array, w_log: jax.Array,
         return jnp.moveaxis(x, 2, 1).reshape(bh, s, p)     # (bh, s, p)
 
     rf, kf, vf, wf = (flat(x.astype(jnp.float32)) for x in (r, k, v, w_log))
-    uf = jnp.tile(u.astype(jnp.float32), (b, 1))           # (bh, p)
+    uf = jnp.tile(u.astype(jnp.float32), (b, 1))[:, None]  # (bh, 1, p)
     sf = state.reshape(bh, p, p).astype(jnp.float32)
 
     seq_spec = pl.BlockSpec((1, chunk, p), lambda i, c: (i, c, 0))
@@ -108,7 +120,7 @@ def _wkv6_call(r: jax.Array, k: jax.Array, v: jax.Array, w_log: jax.Array,
         functools.partial(_kernel, n_chunks=nc),
         grid=(bh, nc),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, p), lambda i, c: (i, 0)),
+                  pl.BlockSpec((1, 1, p), lambda i, c: (i, 0, 0)),
                   pl.BlockSpec((1, p, p), lambda i, c: (i, 0, 0))],
         out_specs=[seq_spec,
                    pl.BlockSpec((1, p, p), lambda i, c: (i, 0, 0))],

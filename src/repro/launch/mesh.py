@@ -21,14 +21,9 @@ ICI_LINKS_PER_CHIP = 3            # usable torus links on a 16x16 slice
 
 def _make_mesh(shape: Tuple[int, ...],
                axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    # jax >= 0.5 wants explicit axis_types (Auto keeps the pre-explicit
-    # sharding semantics); 0.4.x predates jax.sharding.AxisType and rejects
-    # the kwarg, so gate on the attribute
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes keep the pre-explicit sharding semantics the rules assume
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

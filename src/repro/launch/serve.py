@@ -90,6 +90,7 @@ import numpy as np
 from ..configs import ARCHS, get_config
 from ..models import model_api
 from ..serve import Request, ServeEngine, WaveServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def _attach_obs_outputs(engine, args) -> None:
@@ -108,7 +109,10 @@ def _finish_obs_outputs(engine, args) -> None:
 
 
 def _serve_http(engine, hostport: str) -> None:
-    """Run the asyncio streaming frontend until interrupted, then drain."""
+    """Run the asyncio streaming frontend until interrupted, then drain.
+
+    A dead engine pump (a device fault, say) ends the server and re-raises
+    the pump's error, so the process exits non-zero."""
     import asyncio
 
     from ..server import ServeFrontend
@@ -127,7 +131,7 @@ def _serve_http(engine, hostport: str) -> None:
             pass
         finally:
             drained = await frontend.drain()
-            await frontend.close()
+            await frontend.close()        # re-raises the pump's error
             print(f"drained={drained}; served "
                   f"{engine.stats.completed} completed / "
                   f"{engine.stats.shed} shed / "
@@ -137,6 +141,8 @@ def _serve_http(engine, hostport: str) -> None:
         asyncio.run(run())
     except KeyboardInterrupt:
         pass
+    if frontend.pump_error is not None:
+        raise SystemExit(f"engine pump failed: {frontend.pump_error!r}")
 
 
 def _replay_trace(args, cfg, params, engine_kw) -> None:
@@ -216,6 +222,7 @@ def main() -> None:
     ap.add_argument("--trace-out", type=str, default=None, metavar="PATH",
                     help="stream obs trace events to PATH as NDJSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     api = model_api(cfg)

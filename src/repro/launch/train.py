@@ -18,6 +18,7 @@ from ..configs import ARCHS, get_config
 from ..configs.base import ShapeConfig
 from ..runtime import HeartbeatMonitor
 from ..train import TrainConfig, train
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--int8-moments", action="store_true",
                     help="compressed optimizer state")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")

@@ -447,20 +447,6 @@ def moe_dense(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     return out.reshape(b, s, d)
 
 
-def _shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """shard_map with replication/vma checking off, across jax versions:
-    jax >= 0.6 exports ``jax.shard_map`` (``check_vma=``), 0.4.x only has
-    the experimental module (``check_rep=``)."""
-    try:
-        from jax import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def moe_ep_a2a(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     """Expert-parallel MoE with all-to-all dispatch (shard_map).
 
@@ -531,13 +517,13 @@ def moe_ep_a2a(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     # model) axes before dispatch — otherwise every model-column would
     # redundantly dispatch and compute the same tokens (measured 16x waste;
     # EXPERIMENTS.md §Perf cell D)
-    fn = _shard_map_unchecked(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes, e_axis, None),
                   P(None, None),                 # router replicated locally
                   P(e_axis, None, None), P(e_axis, None, None),
                   P(e_axis, None, None)),
-        out_specs=P(batch_axes, e_axis, None))
+        out_specs=P(batch_axes, e_axis, None), check_vma=False)
     wg = p.get("wg", p["w1"])
     return fn(x, p["router"], wg, p["w1"], p["w2"])
 

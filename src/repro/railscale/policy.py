@@ -28,12 +28,7 @@ Three built-ins (select by name via :func:`get_policy`):
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Type
-
-try:  # Protocol is 3.8+; keep a runtime fallback for exotic interpreters
-    from typing import Protocol
-except ImportError:  # pragma: no cover
-    Protocol = object  # type: ignore[assignment]
+from typing import Any, Dict, Optional, Protocol, Type
 
 
 @dataclasses.dataclass(frozen=True)

@@ -108,6 +108,7 @@ class ServeFrontend:
         self._work = threading.Event()
         self._stop = threading.Event()
         self._pump_error: Optional[BaseException] = None
+        self._pump_died = asyncio.Event()      # set on the loop's thread
         self._draining = False
         self._uids = itertools.count()
         self._thread: Optional[threading.Thread] = None
@@ -130,6 +131,8 @@ class ServeFrontend:
         except BaseException as e:            # surface, never die silently
             self._pump_error = e
             self._fail_open()
+            if self._loop is not None:
+                self._loop.call_soon_threadsafe(self._pump_died.set)
 
     def _fail_open(self) -> None:
         """The pump died: terminate every live stream cleanly instead of
@@ -140,6 +143,11 @@ class ServeFrontend:
             if not req.done:
                 req.done = req.truncated = True
             req.fire_finish()
+
+    @property
+    def pump_error(self) -> Optional[BaseException]:
+        """What killed the engine pump, or None while it runs."""
+        return self._pump_error
 
     # ---- lifecycle -----------------------------------------------------------
 
@@ -185,8 +193,21 @@ class ServeFrontend:
             raise self._pump_error
 
     async def serve_forever(self) -> None:
+        """Serve until cancelled or until the engine pump dies; in the
+        latter case :meth:`close` re-raises the pump's error."""
         assert self._server is not None, "call start() first"
-        await self._server.serve_forever()
+        serving = asyncio.ensure_future(self._server.serve_forever())
+        died = asyncio.ensure_future(self._pump_died.wait())
+        try:
+            await asyncio.wait((serving, died),
+                               return_when=asyncio.FIRST_COMPLETED)
+        finally:
+            for task in (serving, died):
+                task.cancel()
+            outcome, _ = await asyncio.gather(serving, died,
+                                              return_exceptions=True)
+        if isinstance(outcome, Exception):
+            raise outcome
 
     # ---- telemetry -----------------------------------------------------------
 
