@@ -10,8 +10,13 @@ clock:
   stats the batch path prints and the ``/metrics`` scrape are one
   source of truth.
 * **Tracing** — :class:`Tracer`/:class:`Span` events covering the
-  request lifecycle (submit → admit/queue-wait → prefill → decode step
-  → guard verify/correct → rail heal → finish), NDJSON-dumpable.
+  request lifecycle (submit → admit/queue-wait → engine step → prefill
+  → decode step {device wait} → guard verify/correct → rail heal →
+  finish), NDJSON-dumpable.  Spans nest: each carries an ``id`` and the ``id`` of
+  the span open around it on its thread (``parent``).
+* **Compile events** — :mod:`repro.obs.compiles`: a ``jit_compile``
+  event for each program lowered during an engine step (one process-wide
+  ``jax.monitoring`` listener; events only, never registry metrics).
 * **Flight recording** — :class:`FlightRecorder` ring buffer of the
   last N events, dumped on chaos failure or ``GuardError``.
 

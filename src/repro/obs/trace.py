@@ -6,8 +6,16 @@ one object per line when dumped:
 
 * point event: ``{"kind": "event", "name": str, "t": float, ...attrs}``
 * span:        ``{"kind": "span", "name": str, "t": float,
-  "dur_s": float, ...attrs}`` (``t`` is the span start; the event is
-  emitted at span end so the stream stays time-ordered by emission)
+  "dur_s": float, "id": int, "parent": int | None, ...attrs}`` (``t`` is
+  the span start; the event is emitted at span end, so a child span is
+  emitted before its parent)
+
+Spans nest: ``id`` is unique per tracer, and ``parent`` is the ``id`` of
+the innermost span still open on the same thread when the span began
+(``None`` at the top).  Each thread keeps its own stack of open spans, so
+a frontend's pump thread and its event loop never interleave.  The push
+and pop happen in :class:`Span` itself, so a tracer subclass that builds
+its own ``Span`` subclasses gets ids and parents too.
 
 Sinks are plain callables ``sink(event: dict)`` — a
 :class:`~repro.obs.recorder.FlightRecorder`'s ``record`` method, a file
@@ -18,6 +26,8 @@ dict, which is what the instrumentation-overhead benchmark toggles.
 
 from __future__ import annotations
 
+import itertools
+import threading
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -30,14 +40,19 @@ class Span:
     """A timed section. Use via ``with tracer.span("prefill", uid=...)``;
     extra attributes can be attached mid-flight with :meth:`set`."""
 
-    __slots__ = ("name", "t0", "attrs", "_tracer", "_done")
+    __slots__ = ("name", "t0", "attrs", "id", "parent", "_tracer", "_done",
+                 "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict) -> None:
         self._tracer = tracer
         self.name = name
-        self.t0 = tracer.clock()
         self.attrs = attrs
         self._done = False
+        self._stack = tracer._open_spans()
+        self.id = next(tracer._ids)
+        self.parent = self._stack[-1].id if self._stack else None
+        self._stack.append(self)
+        self.t0 = tracer.clock()
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -48,8 +63,10 @@ class Span:
             return
         self._done = True
         t1 = self._tracer.clock()
+        self._stack.remove(self)     # innermost, unless ended out of order
         self._tracer._emit({"kind": "span", "name": self.name,
                             "t": self.t0, "dur_s": t1 - self.t0,
+                            "id": self.id, "parent": self.parent,
                             **self.attrs})
 
     def __enter__(self) -> "Span":
@@ -90,6 +107,15 @@ class Tracer:
         self.clock = clock
         self.sinks: List[Sink] = list(sinks or [])
         self.enabled = enabled
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+
+    def _open_spans(self) -> List[Span]:
+        """This thread's stack of open spans, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     def add_sink(self, sink: Sink) -> None:
         self.sinks.append(sink)

@@ -48,11 +48,25 @@ truth behind ``GET /metrics``), request lifecycle events
 into the flight-recorder ring, and per-step backend telemetry lands as
 flag/replay/energy counters + rate gauges.  Pass ``obs=ObsBus(
 enabled=False)`` to disable tracing while keeping the stats registry.
+
+``ServeEngine.step`` traces itself as a tree of spans (each span's
+``parent`` is the ``id`` of the span around it)::
+
+    engine_step                   one per step() that has work
+      prefill {uid, slot, prompt_len, live}
+                                    until the first token is on the host
+      decode_step {step, active, tokens, flags}
+        device_wait                 the host waits while the device steps
+
+``live`` counts the slots whose request already has its first token:
+those the admission stalls.  A program lowered during a step (a new
+shape) adds a ``jit_compile`` event (``repro.obs.compiles``).
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional
 
@@ -62,7 +76,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig, ShapeConfig
 from ..models import model_api
-from ..obs import ObsBus, to_plain
+from ..obs import ObsBus, compiles, to_plain
 from .scheduler import Request, SlotScheduler
 
 Pytree = Any
@@ -208,6 +222,7 @@ class ServeEngine:
         # must replay bit-identically), sharing the engine clock so
         # latency histograms are deterministic under the load harness
         self.obs = obs if obs is not None else ObsBus(clock=clock)
+        compiles.install()
         # execution backend for ALL model GEMMs (a repro.backend name or
         # instance): "emulated" serves every decode matmul on the
         # fault-injecting voltage-scaled array with flag/energy telemetry
@@ -332,8 +347,8 @@ class ServeEngine:
         Returns (last-position logits (1, V), batch-1 decode state, model
         calls spent)."""
         prompt = req.prompt if req.prompt else [BOS]
-        toks = jnp.asarray(np.asarray(prompt, np.int32)[None, :])
         if self._has_prefill:
+            toks = jnp.asarray(np.asarray(prompt, np.int32)[None, :])
             batch: Dict[str, jax.Array] = {"tokens": toks}
             if self.cfg.family == "encdec":
                 t_enc = self.max_len // self.cfg.enc_frames_ratio
@@ -345,10 +360,15 @@ class ServeEngine:
                                         max_len=self.max_len)
             return logits, sub, 1
         sub = self.api.make_decode_state(self._sub_shape)
+        # padded to max_len, so that the per-token slice lowers once and
+        # not once per prompt length
+        toks = np.zeros((1, self.max_len), np.int32)
+        toks[0, :len(prompt)] = prompt
+        toks = jnp.asarray(toks)
         logits = None
-        for t in range(toks.shape[1]):
+        for t in range(len(prompt)):
             logits, sub = self._step(self.params, sub, toks[:, t:t + 1])
-        return logits, sub, toks.shape[1]
+        return logits, sub, len(prompt)
 
     # ---- engine loop ---------------------------------------------------------
 
@@ -439,14 +459,18 @@ class ServeEngine:
                 self._h_queue_wait.observe(wait_s)
                 self.obs.event("request_admitted", uid=req.uid, slot=slot,
                                queue_wait_s=wait_s)
+                live = sum(1 for r in self.scheduler.active.values()
+                           if r.out_tokens)
                 with self.obs.span("prefill", uid=req.uid, slot=slot,
-                                   prompt_len=len(req.prompt)):
+                                   prompt_len=len(req.prompt), live=live):
                     logits, sub, n = self._absorb(req)
+                    self._state = self._inject(self._state, jnp.int32(slot),
+                                               sub)
+                    tok = int(np.asarray(logits)[0].argmax())
                 used += n
                 self.stats.prefill_steps += n
                 self.stats.admitted += 1
-                self._state = self._inject(self._state, jnp.int32(slot), sub)
-                self._emit(slot, req, int(np.asarray(logits)[0].argmax()))
+                self._emit(slot, req, tok)
                 self._maybe_finish(slot, req)   # max_new_tokens == 1
             if deferred:
                 # out of budget mid-batch: hand the slots back and restore
@@ -483,6 +507,12 @@ class ServeEngine:
         decode step.  Idle slots are fed BOS and skipped in argmax/token
         bookkeeping.  Returns model calls used."""
         self._reap_cancelled()
+        with compiles.attributed_to(self.obs), (
+                self.obs.span("engine_step") if not self.scheduler.drained()
+                else contextlib.nullcontext()):
+            return self._admit_and_decode(budget)
+
+    def _admit_and_decode(self, budget: int) -> int:
         used = self._admit(budget)
         self.stats.shed = self.scheduler.n_shed
         self._reap_cancelled()
@@ -492,42 +522,47 @@ class ServeEngine:
             # prefill GEMM telemetry stays in the backend totals but must not
             # pollute the next decode step's flag vector
             self.backend.pop_telemetry()
-        span = self.obs.span("decode_step", step=self.stats.decode_steps,
-                             active=len(self.scheduler.active))
-        logits, self._state = self._step(self.params, self._state,
-                                         jnp.asarray(self._cur[:, None]))
-        self.stats.decode_steps += 1
-        used += 1
-        lg = np.asarray(logits)
-        step_tokens: List[int] = []
-        for slot, req in list(self.scheduler.active.items()):
-            self.stats.slot_busy_steps[slot] += 1
-            tok = int(lg[slot].argmax())
-            self._emit(slot, req, tok)
-            step_tokens.append(tok)
-            self._maybe_finish(slot, req)
-        step_flags: Optional[List[bool]] = None
-        if self._track_backend:
-            tel = self.backend.pop_telemetry()   # this decode step's GEMMs
-            step_flags = [bool(f) for f in (tel.partition_flags or [])]
-            self.stats.backend_step_flags.append(step_flags)
-            self.backend.add_tokens(len(step_tokens))
-            self._publish_backend_step(tel, step_flags)
-            if self.backend.is_guarded:
-                ev = {k: int(getattr(tel, k)) for k in (
-                    "guard_detected", "guard_corrected", "guard_retries",
-                    "guard_heals", "guard_uncorrected")
-                    if getattr(tel, k)}
-                if ev:
-                    self.stats.guard_step_events.append(
-                        {"step": self.stats.decode_steps - 1, **ev})
-                    self.obs.event("guard_step",
-                                   step=self.stats.decode_steps - 1, **ev)
-                    for k, v in ev.items():
-                        self._c_guard.inc(v, kind=k[len("guard_"):])
-        span.set(tokens=len(step_tokens),
-                 flags=sum(step_flags) if step_flags else 0)
-        span.end()
+        with self.obs.span("decode_step", step=self.stats.decode_steps,
+                           active=len(self.scheduler.active)) as span:
+            logits, self._state = self._step(self.params, self._state,
+                                             jnp.asarray(self._cur[:, None]))
+            self.stats.decode_steps += 1
+            used += 1
+            if self.obs.tracer.enabled:
+                # the copy starts when the step ends, not once the host
+                # wakes from the wait, so timing the wait costs no time
+                logits.copy_to_host_async()
+                with self.obs.span("device_wait"):
+                    logits.block_until_ready()
+            lg = np.asarray(logits)
+            step_tokens: List[int] = []
+            for slot, req in list(self.scheduler.active.items()):
+                self.stats.slot_busy_steps[slot] += 1
+                tok = int(lg[slot].argmax())
+                self._emit(slot, req, tok)
+                step_tokens.append(tok)
+                self._maybe_finish(slot, req)
+            step_flags: Optional[List[bool]] = None
+            if self._track_backend:
+                tel = self.backend.pop_telemetry()   # this step's GEMMs
+                step_flags = [bool(f) for f in (tel.partition_flags or [])]
+                self.stats.backend_step_flags.append(step_flags)
+                self.backend.add_tokens(len(step_tokens))
+                self._publish_backend_step(tel, step_flags)
+                if self.backend.is_guarded:
+                    ev = {k: int(getattr(tel, k)) for k in (
+                        "guard_detected", "guard_corrected", "guard_retries",
+                        "guard_heals", "guard_uncorrected")
+                        if getattr(tel, k)}
+                    if ev:
+                        self.stats.guard_step_events.append(
+                            {"step": self.stats.decode_steps - 1, **ev})
+                        self.obs.event("guard_step",
+                                       step=self.stats.decode_steps - 1, **ev)
+                        for k, v in ev.items():
+                            self._c_guard.inc(v, kind=k[len("guard_"):])
+            span.set(tokens=len(step_tokens),
+                     flags=sum(step_flags) if step_flags else 0)
         self._g_queue_depth.set(self.scheduler.n_pending)
         self._g_active.set(len(self.scheduler.active))
         if self.hwloop is not None and step_tokens:

@@ -30,7 +30,8 @@ def test_event_and_span_timing_under_injected_clock():
     assert out[0] == {"kind": "event", "name": "request_submitted",
                       "t": 0.0, "uid": 1}
     assert out[1] == {"kind": "span", "name": "prefill", "t": 2.0,
-                      "dur_s": 0.5, "uid": 1, "tokens": 4}
+                      "dur_s": 0.5, "id": 1, "parent": None, "uid": 1,
+                      "tokens": 4}
 
 
 def test_span_end_is_idempotent_and_exception_sets_error_attr():
@@ -138,4 +139,5 @@ def test_trace_file_sink_streams_ndjson(tmp_path):
     bus.event("after-close")              # must not land in the file
     rows = [json.loads(ln) for ln in path.read_text().splitlines()]
     assert [r["name"] for r in rows] == ["one", "two"]
-    assert rows[1] == {"kind": "span", "name": "two", "t": 0.0, "dur_s": 1.0}
+    assert rows[1] == {"kind": "span", "name": "two", "t": 0.0, "dur_s": 1.0,
+                       "id": 1, "parent": None}
