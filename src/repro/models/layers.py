@@ -14,6 +14,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..backend import current_backend
@@ -51,6 +52,15 @@ def scan_layers(body, carry, stacked, unroll: bool = False,
         stacked_out = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         return carry, stacked_out
     return carry
+
+
+def layer_at(stacked, i):
+    """Layer ``i`` of every leaf of a stacked pytree, read in place: a
+    static slice for a concrete index, a dynamic index for a traced one."""
+    if isinstance(i, (int, np.integer)):
+        return jax.tree.map(lambda a: a[i], stacked)
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), stacked)
 
 
 # ---------------------------------------------------------------------------

@@ -18,12 +18,13 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..backend import current_backend
 from ..backend import matmul as bmm
 from ..configs.base import ModelConfig
 from .layers import (attention, attention_param_specs, chunked_softmax_xent, scan_layers,
-                     decode_attention, embed, embed_param_specs, logits_last,
+                     decode_attention, embed, embed_param_specs, layer_at, logits_last,
                      mlp, mlp_param_specs, rmsnorm, rmsnorm_spec)
 from .shardlib import ParamSpec, shard
 
@@ -526,26 +527,28 @@ def zamba2_decode_step(params, state, tokens, cfg):
     emb0 = x
     period = cfg.shared_attn_period
     n_groups = cfg.n_layers // period
-    regroup = lambda a: a.reshape((n_groups, period) + a.shape[1:])
-    mamba = jax.tree.map(regroup, params["mamba"])
-    ssm_g = regroup(state["ssm"])
-    conv_g = regroup(state["conv"])
     index = state["index"]
     sp = params["shared"]
+    # Every Mamba leaf stays the flat (n_layers, ...) stack, read one layer
+    # at a time where it is used.  A (n_groups, period, ...) regroup makes
+    # XLA slice out each group, and a loop nested in the group loop makes it
+    # copy the whole in_proj stack out of its device layout: both per step.
+    # So one scan over the groups, with the period's layers unrolled in its
+    # body; the barrier keeps each layer's output a bf16 value, as a scan
+    # carry is, so the step gives the same bits as a scan over the layers.
+    stacks = (params["mamba"], state["ssm"], state["conv"])
+    pin = (lambda a: a) if cfg.unroll_layers else jax.lax.optimization_barrier
 
-    def group(carry, inp):
-        x = carry
-        gp, ssm_s, conv_s, kv_l = inp
+    def group(x, inp):
+        g, kv_l = inp
 
-        def inner(c, layer):
-            x = c
-            lp, s1, c1 = layer
+        def inner(x, j):
+            lp, s1, c1 = layer_at(stacks, g * period + j)
             y, s2, c2 = mamba2_step(rmsnorm(x, lp["norm"]), lp, cfg, s1, c1)
-            return x + y, (s2, c2)
+            return pin(x + y), (s2, c2)
 
-        x, (ssm_new, conv_new) = scan_layers(inner, x, (gp, ssm_s, conv_s),
-                                             unroll=cfg.unroll_layers,
-                                             collect=True)
+        x, (ssm_new, conv_new) = scan_layers(inner, x, np.arange(period),
+                                             unroll=True, collect=True)
         # shared attention with its per-application KV cache
         cat = jnp.concatenate([x, emb0], axis=-1)
         h = bmm(cat, sp["down"])
@@ -557,7 +560,7 @@ def zamba2_decode_step(params, state, tokens, cfg):
         return x + h, (ssm_new, conv_new, kv_new)
 
     x, (ssm, conv, kv) = scan_layers(
-        group, x, (mamba, ssm_g, conv_g, state["kv"]),
+        group, x, (np.arange(n_groups), state["kv"]),
         unroll=cfg.unroll_layers, collect=True)
     flat = lambda a: a.reshape((-1,) + a.shape[2:])
     x = rmsnorm(x, params["final_norm"])

@@ -6,13 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.backend import matmul as bmm
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.models import model_api
 from repro.models import ssm as S
 from repro.models.layers import (attention, attention_param_specs,
-                                 chunked_softmax_xent, embed, logits_last,
-                                 rmsnorm)
+                                 chunked_softmax_xent, decode_attention, embed,
+                                 logits_last, mlp, rmsnorm, scan_layers)
 from repro.models.shardlib import init_param_tree
 
 KEY = jax.random.PRNGKey(42)
@@ -89,6 +90,99 @@ def test_decode_matches_parallel_forward(arch):
     scale = float(jnp.abs(full).max()) + 1e-9
     err = float(jnp.abs(dec - full).max()) / scale
     assert err < 2e-2, f"{arch}: decode/parallel mismatch {err}"
+
+
+def _regrouped_zamba2_decode_step(params, state, tokens, cfg):
+    """The hybrid decode step as it was before it read each layer in place:
+    every Mamba leaf and state regrouped to (n_groups, period, ...) and
+    scanned group by group.  The oracle for the in-place step."""
+    x = embed(tokens, params)
+    emb0 = x
+    period = cfg.shared_attn_period
+    n_groups = cfg.n_layers // period
+    regroup = lambda a: a.reshape((n_groups, period) + a.shape[1:])
+    mamba = jax.tree.map(regroup, params["mamba"])
+    index = state["index"]
+    sp = params["shared"]
+
+    def group(x, inp):
+        gp, ssm_s, conv_s, kv_l = inp
+
+        def inner(x, layer):
+            lp, s1, c1 = layer
+            y, s2, c2 = S.mamba2_step(rmsnorm(x, lp["norm"]), lp, cfg, s1, c1)
+            return x + y, (s2, c2)
+
+        x, (ssm_new, conv_new) = scan_layers(inner, x, (gp, ssm_s, conv_s),
+                                             unroll=cfg.unroll_layers,
+                                             collect=True)
+        cat = jnp.concatenate([x, emb0], axis=-1)
+        h = bmm(cat, sp["down"])
+        a = rmsnorm(h, sp["norm_attn"])
+        att, kv_new = decode_attention(a, sp["attn"], cfg, kv_l, index)
+        h = h + att
+        a = rmsnorm(h, sp["norm_mlp"])
+        h = h + mlp(a, sp["mlp"], cfg)
+        return x + h, (ssm_new, conv_new, kv_new)
+
+    x, (ssm, conv, kv) = scan_layers(
+        group, x, (mamba, regroup(state["ssm"]), regroup(state["conv"]),
+                   state["kv"]), unroll=cfg.unroll_layers, collect=True)
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])
+    x = rmsnorm(x, params["final_norm"])
+    logits = logits_last(x, params["embedding"])
+    return logits, {"ssm": flat(ssm), "conv": flat(conv).astype(jnp.bfloat16),
+                    "kv": kv, "index": index + 1}
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+def test_zamba2_decode_step_matches_regrouped_oracle(unroll):
+    """Reading each Mamba layer in place gives the same bits as the
+    regrouped step: logits and every state leaf, over several steps."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("zamba2-2.7b", smoke=True),
+                              unroll_layers=unroll)
+    api = model_api(cfg)
+    params = api.init_params(KEY)
+    toks = jax.random.randint(KEY, (2, 5), 0, cfg.vocab_size)
+    new = jax.jit(api.decode_step)
+    old = jax.jit(lambda p, s, t: _regrouped_zamba2_decode_step(p, s, t, cfg))
+    s_new = s_old = _zero_state(api, ShapeConfig("t", 8, 2, "decode"))
+    for t in range(toks.shape[1]):
+        lg_new, s_new = new(params, s_new, toks[:, t:t + 1])
+        lg_old, s_old = old(params, s_old, toks[:, t:t + 1])
+        np.testing.assert_array_equal(np.asarray(lg_new), np.asarray(lg_old))
+        assert jax.tree.structure(s_new) == jax.tree.structure(s_old)
+        for a, b in zip(jax.tree.leaves(s_new), jax.tree.leaves(s_old)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+
+def test_zamba2_decode_step_reads_layers_in_place():
+    """The compiled hybrid decode step holds one layer's Mamba weights at a
+    time: no (period, ...) group of a weight stack, nor a stack regrouped
+    to (n_groups, period, ...), is ever a value of the program."""
+    import dataclasses
+    import re
+    cfg = dataclasses.replace(get_config("zamba2-2.7b", smoke=True),
+                              n_layers=6, shared_attn_period=3)
+    api = model_api(cfg)
+    dims = S.mamba2_dims(cfg)
+    d, period = cfg.d_model, cfg.shared_attn_period
+    n_groups = cfg.n_layers // period
+    params = jax.eval_shape(api.init_params, KEY)
+    state = jax.eval_shape(
+        lambda: _zero_state(api, ShapeConfig("t", 8, 1, "decode")))
+    toks = jax.ShapeDtypeStruct((1, 1), jnp.int32)
+    hlo = jax.jit(api.decode_step).lower(params, state, toks).compile().as_text()
+    shapes = {tuple(int(n) for n in m.split(",")) for m in
+              re.findall(r"\b(?:bf16|f32)\[([0-9,]+)\]", hlo)}
+    for w in ((d, dims["in_dim"]), (dims["d_inner"], d)):   # in_proj, out_proj
+        for lead in ((period,), (n_groups, period)):
+            for one in ((), (1,)):
+                assert one + lead + w not in shapes, one + lead + w
+        assert {w, (1,) + w} & shapes, f"no single-layer slice {w}"
 
 
 def test_prefill_matches_decode_path():
