@@ -6,3 +6,7 @@ reader per metric and a plain float32 reference per configuration.  From the
 program it takes only the system under test (``repro.serve.ServeEngine``)
 and its spans and counters.
 """
+
+
+class BenchError(RuntimeError):
+    """The benchmark cannot run as asked."""

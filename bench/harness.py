@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib.util
+import itertools
 import json
 import os
 import shutil
@@ -40,7 +41,8 @@ ROOT = BENCH.parent
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
 
-from bench import cost, records, trace as tracemod, workload  # noqa: E402
+from bench import (BenchError, cost, records, trace as tracemod,  # noqa: E402
+                   workload)
 from bench.peaks import peaks  # noqa: E402
 from bench.refs import common  # noqa: E402
 
@@ -54,10 +56,6 @@ TRACE_S = 8.0
 CHECK_TOKENS, CHECK_MIN, CHECK_REQUESTS = 400, 3, 16
 #: Engine spans put into the profiler's trace.
 ENGINE_SPANS = ("prefill", "decode_step")
-
-
-class BenchError(RuntimeError):
-    """The benchmark cannot run as asked."""
 
 
 # ---- the benchmark's files -------------------------------------------------
@@ -93,6 +91,7 @@ def find_cell(bench: Dict[str, Any], name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     conf_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((root / conf_entry["file"]).read_text())
+    cost.blocks(config["model"], conf_entry["file"])
     mix = workload.load_mix(w["traffic"], root / "bench" / "traffic")
     e2e = [m for m in bench["end_to_end"] if _reports(m, name)]
     names = [m["name"] for m in e2e]
@@ -168,12 +167,13 @@ def program_config(config: Dict[str, Any]):
     return cfg
 
 
-def make_weights(config: Dict[str, Any], seed: int):
+def make_weights(config: Dict[str, Any], seed: int, root: Path = ROOT):
     """The benchmark's weights for ``config``, made on the device in one
-    jitted call, checked against the program's parameter tree."""
+    jitted call, checked against the program's parameter tree, and the
+    configuration's reference (``<root>/bench/refs/<name>.py``)."""
     import jax
     from repro.models import model_api
-    ref = common.load(config["name"])
+    ref = common.load(config["name"], root / "bench" / "refs")
     layout = ref.layout(config["model"])
     want = jax.eval_shape(model_api(program_config(config)).init_params,
                           jax.random.PRNGKey(0))
@@ -289,6 +289,7 @@ class Feeder:
         self.due: List[Dict[str, Any]] = []
         self.records: List[Dict[str, Any]] = []
         self.error: Optional[BaseException] = None
+        self._uids = itertools.count()      # one per request submitted
         self._stop = threading.Event()
         if self.closed:
             self.due = [self._record(it, t0) for it in self.items[:clients]]
@@ -316,7 +317,7 @@ class Feeder:
                 self.next += 1
 
         rec["sent"] = time.monotonic()
-        self.engine.submit(Request(uid=len(self.records), prompt=rec["prompt"],
+        self.engine.submit(Request(uid=next(self._uids), prompt=rec["prompt"],
                                    max_new_tokens=rec["max_new_tokens"],
                                    on_token=on_token, on_finish=on_finish))
 
@@ -514,7 +515,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     config, mix = cell.config, cell.mix
     model = config["model"]
     vocab = int(model["vocab_size"])
-    ref, params = make_weights(config, seed)
+    ref, params = make_weights(config, seed, root)
     engine, events = build_engine(config, params, annotate=trace)
     n_warm = warm_up(engine, mix, vocab, seed)
     log(f"set-up: weights and {n_warm} warm-up requests "

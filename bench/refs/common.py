@@ -32,9 +32,9 @@ REFS_DIR = Path(__file__).resolve().parent
 Leaf = Tuple[Tuple[int, ...], str, str]
 
 
-def load(config_name: str):
-    """The reference module ``bench/refs/<config_name>.py``."""
-    path = REFS_DIR / f"{config_name}.py"
+def load(config_name: str, directory: Path = REFS_DIR):
+    """The reference module ``<directory>/<config_name>.py``."""
+    path = Path(directory) / f"{config_name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"no reference {path}")
     spec = importlib.util.spec_from_file_location(

@@ -1,67 +1,84 @@
-"""A checkout in miniature for the tests: the benchmark's own files with
-each configuration cut to the program's smoke sizes and small mixes."""
+"""A checkout in miniature for the tests, built from the benchmark's own
+files alone: every configuration of ``BENCHMARK.json`` at the smoke sizes
+of its program arch (``repro.configs``' ``smoke_config``) with the file's
+``smoke_limits`` as its limits, every mix with its ``smoke`` block in
+place of the keys it names, the same cells, the same references."""
 
 from __future__ import annotations
 
+import dataclasses
 import json
+import shutil
 from pathlib import Path
-from typing import Dict
+from typing import Dict, List
 
-from bench.harness import ROOT
+from bench import harness
 
-#: Smoke sizes per configuration (those of ``repro.configs.*.smoke_config``).
-SIZES = {
-    "phi4-mini-3.8b": dict(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
-                           d_head=32, d_ff=256, vocab_size=512, attn_chunk=32),
-    "zamba2-2.7b": dict(n_layers=4, d_model=128, n_heads=4, n_kv_heads=4,
-                        d_head=32, d_ff=256, vocab_size=512, ssm_state=16,
-                        ssm_d_head=16, ssm_chunk=8, shared_attn_period=2,
-                        attn_chunk=32),
-}
-#: Limits at smoke size, between the program's readings and the int8
-#: control's on the CPU (seeds 0-3: program widest gap at most 0.0054
-#: (phi4) and 0.021 (zamba2), mean gap 6.6e-5 and 2.3e-4; control widest
-#: gap at least 0.013 and 0.056, mean gap 3.2e-4 and 1.6e-3).
-LIMITS = {"phi4-mini-3.8b": {"logit_gap": 0.009, "mean_gap": 1.5e-4},
-          "zamba2-2.7b": {"logit_gap": 0.035, "mean_gap": 6e-4}}
-MIXES = {
-    "chat": {"arrivals": "poisson_open", "rate_rps": 6.0,
-             "prompt": {"median": 16, "sigma": 0.5, "min": 8, "max": 32,
-                        "round_to": 8},
-             "output": {"median": 8, "sigma": 0.5, "min": 4, "max": 16}},
-    "code_burst": {"arrivals": "gamma_open", "rate_rps": 6.0,
-                   "gamma_shape": 0.25,
-                   "prompt": {"median": 16, "sigma": 0.5, "min": 8,
-                              "max": 32, "round_to": 8},
-                   "output": {"median": 6, "sigma": 0.5, "min": 2, "max": 12}},
-    "chat_closed": {"arrivals": "closed_loop", "clients": 6, "think_s": 0.0,
-                    "prompt": {"median": 12, "sigma": 0.5, "min": 4,
-                               "max": 24},
-                    "output": {"median": 8, "sigma": 0.5, "min": 4,
-                               "max": 16}},
-}
+ROOT = harness.ROOT
+#: The engine every smoke configuration runs.
+ENGINE = {"slots": 4, "max_len": 128}
+
+
+def benchmark() -> Dict:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+def config_names() -> List[str]:
+    return [c["name"] for c in benchmark()["configs"]]
+
+
+def cells() -> List[str]:
+    return [w["name"] for w in benchmark()["workloads"]]
+
+
+def smoke_model(conf: Dict) -> Dict:
+    """The file's model with every size in which its arch's smoke config
+    differs from the published one."""
+    from repro.configs import get_config
+    full = get_config(conf["arch"])
+    small = get_config(conf["arch"], smoke=True)
+    return {**conf["model"],
+            **{f.name: getattr(small, f.name)
+               for f in dataclasses.fields(small)
+               if getattr(small, f.name) != getattr(full, f.name)}}
 
 
 def config(name: str) -> Dict:
-    conf = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
-    conf["model"].update(SIZES[name])
-    conf["engine"].update(slots=4, max_len=128)
-    conf["limits"] = dict(LIMITS[name])
+    entry = {c["name"]: c for c in benchmark()["configs"]}[name]
+    conf = json.loads((ROOT / entry["file"]).read_text())
+    conf["model"] = smoke_model(conf)
+    conf["engine"].update(ENGINE)
+    conf["limits"] = dict(conf["smoke_limits"])
     return conf
 
 
+def mix(name: str) -> Dict:
+    raw = json.loads((ROOT / "bench" / "traffic" / f"{name}.json").read_text())
+    small = raw.pop("smoke")
+    return {**raw, **small}
+
+
+def off_chip(monkeypatch) -> None:
+    """Let a whole run go on the CPU: no chip check, no persistent compile
+    cache, no peaks (the roofline and MFU readers stay silent)."""
+    import jax
+    monkeypatch.setattr(harness, "enable_compile_cache", lambda: "off")
+    monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices()[0])
+    monkeypatch.setattr(harness, "peaks", lambda kind: None)
+
+
 def make_root(tmp: Path) -> Path:
-    """``tmp`` laid out as a checkout: BENCHMARK.json, configuration and
-    mix files at smoke size, the same cell names."""
-    small = json.loads((ROOT / "BENCHMARK.json").read_text())
-    small["configs"] = [{"name": n, "file": f"bench/configs/{n}.json"}
-                        for n in SIZES]
-    (tmp / "bench" / "configs").mkdir(parents=True)
-    (tmp / "bench" / "traffic").mkdir(parents=True)
-    for c in small["configs"]:
+    """``tmp`` laid out as a checkout: BENCHMARK.json, and each
+    configuration, reference and mix file that it names, at smoke size."""
+    bench = benchmark()
+    for d in ("configs", "refs", "traffic"):
+        (tmp / "bench" / d).mkdir(parents=True)
+    for c in bench["configs"]:
         (tmp / c["file"]).write_text(json.dumps(config(c["name"])))
-    for w in small["workloads"]:
+        shutil.copy(ROOT / "bench" / "refs" / f"{c['name']}.py",
+                    tmp / "bench" / "refs")
+    for w in bench["workloads"]:
         (tmp / "bench" / "traffic" / f"{w['traffic']}.json").write_text(
-            json.dumps(MIXES[w["traffic"]]))
-    (tmp / "BENCHMARK.json").write_text(json.dumps(small))
+            json.dumps(mix(w["traffic"])))
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp

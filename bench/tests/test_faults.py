@@ -1,8 +1,8 @@
 """Whole runs at smoke size on the CPU, the chip check skipped: a sound
-run comes out correct with every metric of its cell, a run with the
-timed path broken underneath comes out not correct, once per fault a
-served cell can have, and so does a run that judges the int8 control's
-tokens in place of the served ones."""
+run of every cell comes out correct with every metric of its cell; in
+every cell, a run with the timed path broken underneath comes out not
+correct, once per fault a served cell can have, and so does a run that
+judges the int8 control's tokens in place of the served ones."""
 
 import pytest
 
@@ -14,10 +14,7 @@ SECONDS = 2.0
 
 @pytest.fixture
 def root(tmp_path, monkeypatch):
-    import jax
-    monkeypatch.setattr(harness, "enable_compile_cache", lambda: "off")
-    monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices()[0])
-    monkeypatch.setattr(harness, "peaks", lambda kind: None)
+    smoke.off_chip(monkeypatch)
     return smoke.make_root(tmp_path)
 
 
@@ -26,7 +23,7 @@ def _run(root, cell, trace=False):
                        log=lambda m: None)
 
 
-@pytest.mark.parametrize("cell", ["phi4.chat", "zamba2.chat_closed"])
+@pytest.mark.parametrize("cell", smoke.cells())
 def test_sound_run_is_correct(root, cell):
     out = _run(root, cell)
     assert out["correct"], out["checks"]
@@ -74,7 +71,7 @@ def _stale_state(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [_alter_token, _stale_state])
-@pytest.mark.parametrize("cell", ["phi4.chat", "zamba2.chat_closed"])
+@pytest.mark.parametrize("cell", smoke.cells())
 def test_broken_timed_path_is_not_correct(root, monkeypatch, cell, fault):
     fault(monkeypatch)
     out = _run(root, cell)
@@ -83,7 +80,7 @@ def test_broken_timed_path_is_not_correct(root, monkeypatch, cell, fault):
         out["checks"]["logit_gap"]["limit"]
 
 
-@pytest.mark.parametrize("cell", ["phi4.chat", "zamba2.chat_closed"])
+@pytest.mark.parametrize("cell", smoke.cells())
 def test_control_is_not_correct(root, monkeypatch, cell):
     monkeypatch.setattr(harness, "compare", harness.control_compare)
     out = _run(root, cell)

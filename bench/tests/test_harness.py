@@ -294,8 +294,13 @@ def test_benchmark_file_is_consistent():
             assert c in e2e[m["moves"]].get("workloads", cells)
     for w in BENCH["workloads"]:
         assert NAME.match(w["name"]) and w["chips"] == 1
-        assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").is_file()
+        raw = json.loads((ROOT / "bench" / "traffic" /
+                          f"{w['traffic']}.json").read_text())
+        assert raw["smoke"]
         cell = harness.find_cell(BENCH, w["name"])
+        # no request is cut short by the engine's positions
+        assert cell.mix["prompt"]["max"] + cell.mix["output"]["max"] <= \
+            cell.config["engine"]["max_len"]
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
         assert len(cell.end_to_end) >= 2 and cell.per_layer
     for c in BENCH["configs"]:
@@ -303,7 +308,8 @@ def test_benchmark_file_is_consistent():
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert conf["name"] == c["name"] and c["reduced"] == conf["reduced"]
         assert (ROOT / "bench" / "refs" / f"{c['name']}.py").is_file()
-        assert conf["limits"]
+        assert conf["limits"] and conf["smoke_limits"]
+        assert 0 < conf["smoke_logit_rtol"] < 1
     n = len(BENCH["workloads"])
     assert n <= 24 and 1 <= BENCH["run_seconds"] <= 51
     runs = 2 + 14 * 24
@@ -362,3 +368,117 @@ def test_stream_checks():
     bad["tokens"] = [10 ** 9]
     assert harness.stream_checks([good, short, never, bad], 1000) == {
         "never_came": 1, "short_streams": 1, "tokens_outside_vocab": 1}
+
+
+# ---- cost by layer kind ------------------------------------------------------------
+
+#: Smoke-size widths with every kind of block: layers of attention and
+#: experts, Mamba2 (with a conv bias) and experts, Mamba2 and an MLP, and
+#: one shared attention block; 2 of 8 experts held, top-2, a shared expert.
+KINDS_MODEL = {"d_model": 128, "n_heads": 4, "n_kv_heads": 2, "d_head": 32,
+               "d_ff": 256, "act": "swiglu", "vocab_size": 512,
+               "vocab_pad_multiple": 256, "ssm_state": 16, "ssm_d_head": 16,
+               "ssm_expand": 2, "ssm_conv": 4, "ssm_conv_bias": True,
+               "n_experts": 8, "experts_held": 2, "top_k": 2,
+               "d_expert": 64, "d_shared_expert": 96,
+               "layer_types": [["attention", "experts"], ["mamba2", "experts"],
+                               ["mamba2", "mlp"], "shared_attention"]}
+
+
+def test_cost_kinds_by_hand():
+    m = KINDS_MODEL
+    # attention: q 4 x 32 = 128, K/V 2 x 32 = 64 wide
+    attn = 128 * 128 + 2 * 128 * 64 + 128 * 128
+    mlp = 3 * 128 * 256
+    # mamba2: inner 256, 16 heads of 16, state 16; conv over x B C = 288;
+    # in_proj to z x B C dt = 256 + 288 + 16 = 560
+    mamba_mats = 128 * 560 + 256 * 128
+    mamba = mamba_mats + (4 + 1) * 288
+    mamba_f32 = 128 + 3 * 16 + 256
+    shared = 2 * 128 * 128 + attn + mlp
+    router, shared_expert, expert = 128 * 8, 3 * 128 * 96, 3 * 128 * 64
+    counts = cost.param_counts(m)
+    assert counts["bf16"] == (512 * 128 + attn + mlp + 2 * mamba + shared
+                              + 2 * (router + shared_expert + 2 * expert))
+    assert counts["f32"] == 128 * 5 + 2 * mamba_f32 + 2 * 128
+    assert cost.weight_bytes(m) == 2 * counts["bf16"] + 4 * counts["f32"]
+    tok = (2 * attn + 2 * mlp + 2 * shared
+           + 2 * (2 * mamba_mats + 2 * 4 * 288 + 4 * 16 * 16 * 16)
+           + 2 * 2 * (router + shared_expert))
+    assert cost.token_flops(m) == tok
+    assert cost.n_attention_layers(m) == 2
+    assert cost.attention_flops(m, 5) == 2 * 4 * 5 * 4 * 32
+    assert cost.kv_bytes_per_position(m) == 2 * (2 * 2 * 32 * 2)
+    state = 16 * 16 * 16 * 4 + 3 * 288 * 2
+    assert cost.state_bytes_per_slot(m) == 2 * state
+    # routed experts: by default top-k x held / published a token a layer
+    assert cost.expected_pairs(m, 1) == 2 * 2 * 2 / 8
+    assert cost.expected_hit(m, 1) == cost.expected_pairs(m, 1)
+    assert cost.expected_hit(m, 2) == pytest.approx(2 * 2 * (1 - 0.75 ** 2))
+    head = 2 * 128 * 512
+    att = cost.attention_flops(m, 5) + cost.attention_flops(m, 9)
+    assert cost.decode_flops(m, [5]) == tok + head \
+        + cost.attention_flops(m, 5) + 1.0 * 2 * expert
+    assert cost.decode_flops(m, [5, 9]) == 2 * (tok + head) + att \
+        + 2.0 * 2 * expert
+    assert cost.prefill_flops(m, 4) == 4 * tok + \
+        cost.attention_flops(m, 10) + head + 4.0 * 2 * expert
+    kv = cost.kv_bytes_per_position(m)
+    hit2 = 2 * 2 * (1 - 0.75 ** 2)
+    assert cost.decode_bytes(m, [5, 9]) == (
+        cost.weight_bytes(m) - (4 - hit2) * expert * 2 + (14 + 2) * kv
+        + 2 * 2 * 2 * state)
+    assert cost.decode_bytes(m, [5]) == (
+        cost.weight_bytes(m) - (4 - 1) * expert * 2 + (5 + 1) * kv
+        + 2 * 2 * state)
+
+
+def test_unknown_layer_kind_is_refused(tmp_path):
+    model = dict(KINDS_MODEL, layer_types=["mamba2", ["moe", "mlp"]])
+    with pytest.raises(harness.BenchError, match="'moe'.*known"):
+        cost.blocks(model)
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "bench" / "traffic").mkdir()
+    (tmp_path / "bench" / "traffic" / "chat.json").write_text(
+        json.dumps(MIXES["chat"]))
+    conf = dict(CONFIGS["phi4-mini-3.8b"],
+                model=dict(CONFIGS["phi4-mini-3.8b"]["model"],
+                           family="other"))
+    (tmp_path / "bench" / "configs" / "x.json").write_text(json.dumps(conf))
+    bench = {"configs": [{"name": "x", "file": "bench/configs/x.json"}],
+             "workloads": [{"name": "x.chat", "config": "x",
+                            "traffic": "chat", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    with pytest.raises(harness.BenchError,
+                       match="bench/configs/x.json.*'other'"):
+        harness.find_cell(bench, "x.chat", tmp_path)
+    conf["model"]["layer_types"] = ["attention", "moe"]
+    (tmp_path / "bench" / "configs" / "x.json").write_text(json.dumps(conf))
+    with pytest.raises(harness.BenchError,
+                       match="bench/configs/x.json.*'moe'"):
+        harness.find_cell(bench, "x.chat", tmp_path)
+
+
+#: What the three device readers gave on ``_pinned_record`` before the
+#: cost model was counted by layer kind, to the last digit.
+PINNED = {
+    "phi4-mini-3.8b": {"prefill_mfu": 16.568332475126905,
+                       "decode_mfu": 0.25986188345177663,
+                       "decode_hbm_roofline": 46.86378301994302},
+    "zamba2-2.7b": {"prefill_mfu": 16.5289081285956,
+                    "decode_mfu": 0.22558421983079527,
+                    "decode_hbm_roofline": 29.9355748962149},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_device_readers_read_as_before(name):
+    red = {"window_s": 4.0, "busy_s": 3.0,
+           "programs": {"jit_prefill": {"device_s": 0.006, "count": 2},
+                        "jit_decode_step": {"device_s": 0.06, "count": 3},
+                        "jit_decode_step(7)": {"device_s": 0.06, "count": 3}},
+           "device_ops": [], "idle_gaps": []}
+    rec = _record(events=_events(), trace=red, trace_window=(0.0, 10.0),
+                  window=(0.0, 10.0), model=CONFIGS[name]["model"])
+    for metric, want in PINNED[name].items():
+        assert harness.reader(metric)(rec) == want, metric

@@ -5,7 +5,7 @@ nesting, and traced runs at smoke size that print the three metrics."""
 
 import pytest
 
-from bench import harness
+from bench import harness, steps
 from bench.tests import smoke
 
 WINDOW = (10.0, 20.0)
@@ -100,10 +100,7 @@ def test_profiler_bridge_spans_nest():
 
 @pytest.fixture
 def root(tmp_path, monkeypatch):
-    import jax
-    monkeypatch.setattr(harness, "enable_compile_cache", lambda: "off")
-    monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices()[0])
-    monkeypatch.setattr(harness, "peaks", lambda kind: None)
+    smoke.off_chip(monkeypatch)
     # profile part of the window, as on the chip, so that decode steps
     # outside the profiled stretch remain for decode_host_ms
     monkeypatch.setattr(harness, "TRACE_S", 0.8)
@@ -126,3 +123,23 @@ def test_traced_run_prints_the_engine_metrics(root, cell, want):
         assert m["compiles_in_window"] == 0
     if "admit_stall_share" in want:
         assert 0 < m["admit_stall_share"] <= 100
+
+
+def test_rebuilt_decode_rows_match_the_engine(root):
+    """Every request the feeder submits has a uid of its own (a closed loop
+    submits several at once), so the work rebuilt from the events has, in
+    every batched step of the window, the rows the engine reports active."""
+    cell = harness.find_cell(harness.load_benchmark(root),
+                             "zamba2.chat_closed", root)
+    vocab = cell.config["model"]["vocab_size"]
+    _, params = harness.make_weights(cell.config, 3, root)
+    engine, events = harness.build_engine(cell.config, params,
+                                          annotate=False)
+    harness.warm_up(engine, cell.mix, vocab, 3)
+    win = harness.drive(engine, cell.mix, 3, 2.0, vocab)
+    uids = [e["uid"] for e in events
+            if e.get("name") == "request_submitted" and e["uid"] >= 0]
+    assert len(uids) == len(set(uids)) == len(win.records)
+    spans = steps.spans(events, "decode_step", win.t0, win.t1)
+    keys = steps.work(events, win.t0, win.t1)["decode_keys"]
+    assert spans and [len(k) for k in keys] == [e["active"] for e in spans]

@@ -13,11 +13,6 @@ from bench import harness
 from bench.refs import common
 from bench.tests import smoke
 
-#: Relative RMS of the logits against the reference, per configuration:
-#: above the program's readings (seeds 0-2: at most 0.011 phi4, 0.029
-#: zamba2; bf16 weights and activations) and below the int8 control's
-#: (at least 0.034 and 0.066).
-LOGIT_RTOL = {"phi4-mini-3.8b": 0.02, "zamba2-2.7b": 0.045}
 MAX_LEN = 128
 
 
@@ -31,13 +26,14 @@ def _rel(a, b):
 
 
 def _program_logits(conf, params, prompt, tokens):
-    """Logits of the program's own path: prefill (dense) or one decode step
-    per prompt token (hybrid), then cached decode of ``tokens[:-1]``."""
+    """Logits of the program's own path, as the engine takes it: one
+    decode step per prompt token (SSM and hybrid families) or a prefill,
+    then cached decode of ``tokens[:-1]``."""
     from repro.configs.base import ShapeConfig
     from repro.models import model_api
     api = model_api(harness.program_config(conf))
     step = jax.jit(api.decode_step)
-    if conf["model"]["family"] == "dense":
+    if api.cfg.family not in ("ssm", "hybrid"):
         lg, st = jax.jit(api.prefill, static_argnames=("max_len",))(
             params, {"tokens": jnp.asarray([prompt])}, max_len=MAX_LEN)
     else:
@@ -58,9 +54,12 @@ def _ref_logits(ref, conf, params, prompt, tokens, mm):
     return np.asarray(mm(h, params["embedding"].T))
 
 
-@pytest.mark.parametrize("name", sorted(smoke.SIZES))
+@pytest.mark.parametrize("name", smoke.config_names())
 @pytest.mark.parametrize("seed", [0, 7])
 def test_cached_decode_agrees_with_the_reference(name, seed):
+    """Relative RMS of the logits against the reference, under the file's
+    ``smoke_logit_rtol`` (set between the program's readings and the int8
+    control's, as its ``smoke_readings`` say), and the control over it."""
     conf = smoke.config(name)
     ref, params = harness.make_weights(conf, seed)
     rng = np.random.default_rng(seed)
@@ -69,8 +68,8 @@ def test_cached_decode_agrees_with_the_reference(name, seed):
     prog = _program_logits(conf, params, prompt, tokens)
     want = _ref_logits(ref, conf, params, prompt, tokens, common.mm_f32)
     ctrl = _ref_logits(ref, conf, params, prompt, tokens, common.mm_int8)
-    assert _rel(prog, want) <= LOGIT_RTOL[name]
-    assert _rel(ctrl, want) > LOGIT_RTOL[name]
+    assert _rel(prog, want) <= conf["smoke_logit_rtol"]
+    assert _rel(ctrl, want) > conf["smoke_logit_rtol"]
 
 
 def _served(conf, params, seed):
@@ -91,7 +90,7 @@ def _served(conf, params, seed):
     return [{"prompt": r.prompt, "tokens": r.out_tokens} for r in reqs]
 
 
-@pytest.mark.parametrize("name", sorted(smoke.SIZES))
+@pytest.mark.parametrize("name", smoke.config_names())
 @pytest.mark.parametrize("seed", [0, 1])
 def test_served_tokens_pass_and_the_control_fails(name, seed):
     conf = smoke.config(name)

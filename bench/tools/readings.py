@@ -46,7 +46,7 @@ def main() -> None:
     vocab = int(model["vocab_size"])
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t = time.time()
-        ref, params = harness.make_weights(cell.config, seed)
+        ref, params = harness.make_weights(cell.config, seed, root)
         engine, _ = harness.build_engine(cell.config, params, annotate=False)
         harness.warm_up(engine, cell.mix, vocab, seed)
         win = harness.drive(engine, cell.mix, seed, args.seconds, vocab)

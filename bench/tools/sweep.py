@@ -62,7 +62,7 @@ def main() -> None:
     harness.require_chips(cell.chips)
     harness.enable_compile_cache()
     vocab = int(cell.config["model"]["vocab_size"])
-    _, params = harness.make_weights(cell.config, args.seed)
+    _, params = harness.make_weights(cell.config, args.seed, root)
     engine, _ = harness.build_engine(cell.config, params, annotate=False)
     harness.warm_up(engine, cell.mix, vocab, args.seed)
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):

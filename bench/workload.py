@@ -8,7 +8,9 @@ One generator reads every mix.  A mix file holds only parameters::
      "clients": 32, "think_s": 0.0,    # closed_loop
      "prompt": {"median": 512, "sigma": 0.8, "min": 256, "max": 1536,
                 "round_to": 256},      # clipped lognormal, rounded up
-     "output": {"median": 160, "sigma": 0.7, "min": 16, "max": 480}}
+     "output": {"median": 160, "sigma": 0.7, "min": 16, "max": 480},
+     "smoke": {...}}                   # top-level keys the tests' smoke
+                                       # size replaces; ignored here
 
 Every seed gets the same work in the same order: lengths and inter-arrival
 gaps are the quantiles ``(i + 0.5) / n`` of their distributions (a
@@ -49,6 +51,7 @@ class Item:
 
 def load_mix(name: str, directory: Path = TRAFFIC_DIR) -> Dict:
     mix = json.loads((Path(directory) / f"{name}.json").read_text())
+    mix.pop("smoke", None)
     if mix.get("arrivals") not in ARRIVALS:
         raise ValueError(f"mix {name}: arrivals must be one of {ARRIVALS}")
     return mix
