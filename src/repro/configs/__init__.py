@@ -2,9 +2,10 @@
 
 from typing import Callable, Dict, Tuple
 
-from . import (granite_20b, grok_1_314b, llama4_scout_17b_a16e,
-               llava_next_mistral_7b, phi4_mini_3_8b, qwen1_5_110b,
-               rwkv6_1_6b, seamless_m4t_medium, starcoder2_3b, zamba2_2_7b)
+from . import (granite_4_0_h_small, granite_4_0_h_small_share, granite_20b,
+               grok_1_314b, llama4_scout_17b_a16e, llava_next_mistral_7b,
+               phi4_mini_3_8b, qwen1_5_110b, rwkv6_1_6b, seamless_m4t_medium,
+               starcoder2_3b, zamba2_2_7b)
 from .base import (SHAPES, ModelConfig, ShapeConfig, cell_is_runnable,
                    pad_vocab)
 
@@ -19,6 +20,8 @@ _MODULES = {
     "seamless-m4t-medium": seamless_m4t_medium,
     "zamba2-2.7b": zamba2_2_7b,
     "rwkv6-1.6b": rwkv6_1_6b,
+    "granite-4.0-h-small": granite_4_0_h_small,
+    "granite-4.0-h-small-share": granite_4_0_h_small_share,
 }
 
 ARCHS: Dict[str, ModelConfig] = {k: m.CONFIG for k, m in _MODULES.items()}

@@ -6,6 +6,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
+#: The kinds a layer of ``ModelConfig.layer_types`` is made of, in order:
+#: one mixer (``mamba2`` or ``attention``), then an ``experts`` block.
+LAYER_KINDS = ("mamba2", "attention", "experts")
+
 VOCAB_PAD_MULTIPLE = 256   # vocab padded so TP-16 sharding always divides
 
 
@@ -17,6 +21,9 @@ def pad_vocab(v: int, multiple: int = VOCAB_PAD_MULTIPLE) -> int:
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | encdec | vlm
+    #   hybrid: zamba2's shared block every ``shared_attn_period`` Mamba2
+    #   layers, or, where ``layer_types`` is given, the kinds it lists
+    #   (``per_layer``)
     n_layers: int
     d_model: int
     n_heads: int
@@ -29,6 +36,10 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     rope_theta: float = 10_000.0
     attn_chunk: int = 1024           # q-chunk for memory-bounded attention
+    position_embedding_type: str = "rope"   # rope | nope (no position code)
+    attention_multiplier: Optional[float] = None  # score scale; None:
+    #                                  1/sqrt(d_head)
+    rms_norm_eps: float = 1e-6
     # mlp
     act: str = "swiglu"              # swiglu | gelu
     # moe
@@ -40,13 +51,28 @@ class ModelConfig:
     #   "ffn":    experts replicated, FFN hidden dim sharded (any E)
     capacity_factor: float = 1.25
     shared_expert: bool = False      # llama4-style always-on expert
+    # hybrid layer_types models: experts of width d_expert, of which this
+    # chip holds the first experts_held (0: all n_experts), and an
+    # always-on shared expert of width d_shared_expert
+    d_expert: int = 0
+    experts_held: int = 0
+    d_shared_expert: int = 0
     # ssm (mamba2 / rwkv6)
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_d_head: int = 64
     ssm_chunk: int = 64
+    ssm_conv_bias: bool = False
     # hybrid (zamba2): one shared attention block applied every k ssm layers
     shared_attn_period: int = 0
+    # hybrid, one entry per layer: the kinds of LAYER_KINDS it is made of
+    # (a JSON list of lists is read as written)
+    layer_types: Tuple[Tuple[str, ...], ...] = ()
+    # granite-style multipliers: embedding x, each residual branch x,
+    # logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # enc-dec
     n_enc_layers: int = 0
     enc_frames_ratio: int = 4        # encoder frames = seq_len // ratio
@@ -72,6 +98,42 @@ class ModelConfig:
     #                                  stationary over data x model, psum acts)
     fused_rwkv_proj: bool = False    # single fused r/k/v/g/w projection
     ssm_bf16: bool = False           # bf16 recurrence internals (f32 decays)
+
+    def __post_init__(self) -> None:
+        types = tuple((k,) if isinstance(k, str) else tuple(k)
+                      for k in self.layer_types)
+        object.__setattr__(self, "layer_types", types)
+        unknown = {k for kinds in types for k in kinds} - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f"{self.name}: unknown layer kinds "
+                             f"{sorted(unknown)}; known: {LAYER_KINDS}")
+        if types and len(types) != self.n_layers:
+            raise ValueError(f"{self.name}: {len(types)} layer_types for "
+                             f"{self.n_layers} layers")
+
+    @property
+    def per_layer(self) -> bool:
+        """A hybrid whose layers are the kinds ``layer_types`` lists
+        (granite-4.0-h), and not zamba2's shared block every
+        ``shared_attn_period`` Mamba2 layers: the one test that tells the
+        two hybrid programs apart (``models.ssm.hybrid_program``)."""
+        return self.family == "hybrid" and bool(self.layer_types)
+
+    @property
+    def layer_period(self) -> int:
+        """The fewest leading layers whose repetition gives ``layer_types``."""
+        types, n = self.layer_types, len(self.layer_types)
+        return next(p for p in range(1, n + 1) if n % p == 0 and all(
+            types[i] == types[i % p] for i in range(n)))
+
+    def layer_count(self, kind: str) -> int:
+        """Layers of ``layer_types`` that hold a ``kind`` block."""
+        return sum(kind in kinds for kinds in self.layer_types)
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts this chip holds: the first ``experts_held``."""
+        return self.experts_held or self.n_experts
 
     @property
     def padded_vocab(self) -> int:

@@ -11,6 +11,8 @@
     api.slot_slice / slot_update / slot_reset -> per-slot state surgery
         (continuous batching: one batch row is admitted/evicted without
         recomputing the rest of the batch)
+    api.counts_routes / counting_routes -> a decode state that counts the
+        expert routing of its steps (hybrids with expert layers)
 """
 
 from __future__ import annotations
@@ -85,7 +87,7 @@ class ModelAPI:
         if f == "ssm":
             return ssm.rwkv6_param_tree(self.cfg)
         if f == "hybrid":
-            return ssm.zamba2_param_tree(self.cfg)
+            return ssm.hybrid_program(self.cfg).param_tree(self.cfg)
         if f == "encdec":
             return encdec.param_specs(self.cfg)
         raise ValueError(f"unknown family {f}")
@@ -103,7 +105,8 @@ class ModelAPI:
             if f == "ssm":
                 return ssm.rwkv6_loss(params, batch, self.cfg)
             if f == "hybrid":
-                return ssm.zamba2_loss(params, batch, self.cfg)
+                return ssm.hybrid_program(self.cfg).loss(params, batch,
+                                                         self.cfg)
             if f == "encdec":
                 return encdec.loss_fn(params, batch, self.cfg)
         raise ValueError(f)
@@ -128,7 +131,8 @@ class ModelAPI:
             if f == "ssm":
                 return ssm.rwkv6_decode_step(params, state, tokens, self.cfg)
             if f == "hybrid":
-                return ssm.zamba2_decode_step(params, state, tokens, self.cfg)
+                return ssm.hybrid_program(self.cfg).decode_step(
+                    params, state, tokens, self.cfg)
             if f == "encdec":
                 return encdec.decode_step(params, state, tokens, self.cfg)
         raise ValueError(f)
@@ -170,7 +174,8 @@ class ModelAPI:
         if f == "ssm":
             return ssm.rwkv6_state_specs(cfg, b)
         if f == "hybrid":
-            return ssm.zamba2_state_specs(cfg, b, s, long_context=long_ctx)
+            return ssm.hybrid_program(cfg).state_specs(
+                cfg, b, s, long_context=long_ctx)
         if f == "encdec":
             return encdec.decode_state_specs(cfg, b, s)
         raise ValueError(f)
@@ -222,6 +227,23 @@ class ModelAPI:
                 leaf, jnp.zeros(shape1, leaf.dtype), slot, axis=ax)
         return jax.tree.map(zero, self.decode_state_specs(shape), state,
                             is_leaf=_is_spec)
+
+    # ---- expert routing counted in the decode state ---------------------------
+
+    @property
+    def counts_routes(self) -> bool:
+        """Whether a decode state can count the expert routing of its steps
+        (a hybrid whose layers hold experts)."""
+        return (self.cfg.family == "hybrid"
+                and self.cfg.layer_count("experts") > 0)
+
+    @staticmethod
+    def counting_routes(state: Params) -> Params:
+        """``state`` with a count, from zero, of the routing its decode
+        steps make: under ``"routed"``, the (token, held expert) pairs and
+        the held experts hit (int32 (2,), on the device).  Slot surgery
+        takes the state without it."""
+        return {**state, "routed": jnp.zeros((2,), jnp.int32)}
 
 
 def model_api(cfg: ModelConfig, backend: Any = None) -> ModelAPI:

@@ -133,8 +133,9 @@ def _qkv(x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array):
     q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.d_head)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.d_head)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding_type == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -160,11 +161,20 @@ def _mask(q_pos: jax.Array, k_pos: jax.Array, window: Optional[int],
     return keep
 
 
+def _scaled(scores: jax.Array, d_head: int,
+            scale: Optional[float]) -> jax.Array:
+    """Attention scores times ``scale``, or over sqrt(d_head) (None)."""
+    if scale is None:
+        return scores / jnp.sqrt(jnp.float32(d_head))
+    return scores * jnp.float32(scale)
+
+
 def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, keep: jax.Array,
-          d_head: int, scores_f32: bool = True) -> jax.Array:
+          d_head: int, scores_f32: bool = True,
+          scale: Optional[float] = None) -> jax.Array:
     """q:(b,qs,h,d) k,v:(b,ks,h,d) keep:(qs,ks) -> (b,qs,h,d).  f32 softmax."""
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d_head))
+    scores = _scaled(scores, d_head, scale)
     scores = jnp.where(keep[None, None], scores, NEG_INF)
     if not scores_f32:
         # bf16 score pipeline: subtract the running max first so bf16's 8-bit
@@ -178,8 +188,8 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, keep: jax.Array,
 
 
 def _sdpa_grouped(q: jax.Array, k: jax.Array, v: jax.Array, keep: jax.Array,
-                  d_head: int, n_kv: int,
-                  scores_f32: bool = True) -> jax.Array:
+                  d_head: int, n_kv: int, scores_f32: bool = True,
+                  scale: Optional[float] = None) -> jax.Array:
     """GQA without materializing repeated K/V: q reshaped (b, qs, kv, g, d)
     einsummed against the raw (b, ks, kv, d) K/V (§Perf: removes the
     heads/kv_heads-fold byte inflation of _repeat_kv)."""
@@ -187,7 +197,7 @@ def _sdpa_grouped(q: jax.Array, k: jax.Array, v: jax.Array, keep: jax.Array,
     g = h // n_kv
     qg = q.reshape(b, qs, n_kv, g, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d_head))
+    scores = _scaled(scores, d_head, scale)
     scores = jnp.where(keep[None, None, None], scores, NEG_INF)
     if not scores_f32:
         scores = (scores - jax.lax.stop_gradient(
@@ -231,8 +241,9 @@ def attention(x: jax.Array, p: Params, cfg: ModelConfig,
         keep = _mask(q_pos, k_pos, cfg.sliding_window, causal)
         if cfg.gqa_grouped:
             return _sdpa_grouped(qc, k, v, keep, cfg.d_head, cfg.n_kv_heads,
-                                 cfg.attn_scores_f32)
-        return _sdpa(qc, k, v, keep, cfg.d_head, cfg.attn_scores_f32)
+                                 cfg.attn_scores_f32, cfg.attention_multiplier)
+        return _sdpa(qc, k, v, keep, cfg.d_head, cfg.attn_scores_f32,
+                     cfg.attention_multiplier)
 
     if n_chunk == 1:
         o = one_chunk(0)
@@ -347,7 +358,7 @@ def decode_attention(x: jax.Array, p: Params, cfg: ModelConfig,
         if cfg.sliding_window is not None:
             valid &= k_pos[None, :] > idx[:, None] - cfg.sliding_window
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(cfg.d_head))
+    scores = _scaled(scores, cfg.d_head, cfg.attention_multiplier)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     o = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(b, 1, cfg.q_dim)
@@ -542,6 +553,72 @@ def moe(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     if cfg.moe_impl == "ep_a2a":
         return moe_ep_a2a(x, p, cfg)
     return moe_dense(x, p, cfg)
+
+
+def held_experts_param_specs(cfg: ModelConfig, layers: int) -> Params:
+    """A stack of expert blocks as this chip holds them: its pre-norm, the
+    router over every one of the ``n_experts``, the ``n_experts_held``
+    routed SwiGLU experts held here and the shared expert."""
+    L, d, f, e = layers, cfg.d_model, cfg.d_expert, cfg.n_experts_held
+    bf = jnp.bfloat16
+    return {
+        "norm": ParamSpec((L, d), jnp.float32, ("layers", None), init="ones"),
+        "router": ParamSpec((L, d, cfg.n_experts), bf,
+                            ("layers", "fsdp", None)),
+        "wg": ParamSpec((L, e, d, f), bf, ("layers", "expert", "fsdp", None)),
+        "w1": ParamSpec((L, e, d, f), bf, ("layers", "expert", "fsdp", None)),
+        "w2": ParamSpec((L, e, f, d), bf, ("layers", "expert", None, "fsdp")),
+        "shared": mlp_param_specs(cfg, layers=L, d_ff=cfg.d_shared_expert),
+    }
+
+
+def held_experts(x: jax.Array, p: Params, cfg: ModelConfig,
+                 eps: float) -> Tuple[jax.Array, jax.Array]:
+    """One chip's share of a dropless expert block, its RMSNorm
+    (``p["norm"]``) included.
+
+    The router scores all ``n_experts`` in float32 from the float32 norm
+    (bf16 weight): rounded to bf16, near ties of logits would send tokens
+    to other experts than the reference's.  Each token takes its ``top_k``
+    logits, whose softmax are its gates.  Each of the ``n_experts_held``
+    experts held here (the first ones) adds its SwiGLU output times its
+    gate for the tokens routed to it; routes to experts held elsewhere add
+    nothing, and the gates are not renormalised over the held ones.  The
+    shared expert is added in full.  Every held expert computes every token
+    (a dense pass: no capacity, nothing is dropped).
+    x: (b, s, d) -> (out (b, s, d), expert ids (b*s, top_k)).
+    """
+    b, s, d = x.shape
+    normed = rmsnorm(x.astype(jnp.float32), p["norm"], eps).reshape(b * s, d)
+    with jax.default_matmul_precision("highest"):
+        logits = bmm(normed, p["router"].astype(jnp.float32))
+    x = normed.astype(x.dtype).reshape(b, s, d)
+    xt = x.reshape(b * s, d)
+    top, idx = jax.lax.top_k(logits, cfg.top_k)
+    gate = jax.nn.softmax(top, axis=-1)                       # (t, k)
+    held = jnp.arange(cfg.n_experts_held)
+    gates = jnp.where(idx[:, :, None] == held, gate[:, :, None], 0.0).sum(1)
+    if current_backend().is_ideal:
+        # lint: allow=RP001 ideal-only fast path; non-ideal branch below bmm's
+        up = lambda wkey: jnp.einsum("td,edf->etf", xt, p[wkey])
+        down = lambda h: jnp.einsum("etf,efd->etd", h, p["w2"])  # lint: allow=RP001 ideal-only
+    else:
+        up = lambda wkey: jnp.stack(
+            [bmm(xt, p[wkey][e]) for e in range(cfg.n_experts_held)])
+        down = lambda h: jnp.stack(
+            [bmm(h[e], p["w2"][e]) for e in range(cfg.n_experts_held)])
+    h = jax.nn.silu(up("wg").astype(jnp.float32)).astype(xt.dtype) * up("w1")
+    y = down(h)                                               # (E, t, d)
+    out = jnp.einsum("etd,te->td", y, gates.astype(y.dtype))
+    return out.reshape(b, s, d) + mlp(x, p["shared"], cfg), idx
+
+
+def route_counts(idx: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Of the top-k expert ids ``idx`` (t, k): the (token, held expert)
+    pairs, and the held experts at least one token is routed to.  int32
+    (2,)."""
+    hit = idx[:, :, None] == jnp.arange(cfg.n_experts_held)   # (t, k, E)
+    return jnp.stack([hit.sum(), hit.any((0, 1)).sum()]).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,10 @@
 * RWKV6 "Finch" time-mix with data-dependent decay + channel-mix
   [arXiv:2404.05892];
 * Zamba2 hybrid: stacked Mamba2 blocks with one *shared* attention+MLP block
-  applied every ``shared_attn_period`` layers.
+  applied every ``shared_attn_period`` layers;
+* per-layer hybrids (granite-4.0-h): each layer is the kinds its
+  ``layer_types`` entry lists (a Mamba2 or attention mixer, then an expert
+  block), each a pre-norm residual branch scaled by ``residual_multiplier``.
 
 Training uses chunked matmul forms (MXU-friendly — these are also the Pallas
 kernel targets in repro.kernels); decode uses O(1) recurrent state updates.
@@ -13,8 +16,9 @@ kernel targets in repro.kernels); decode uses O(1) recurrent state updates.
 
 from __future__ import annotations
 
+import collections
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,10 +26,11 @@ import numpy as np
 
 from ..backend import current_backend
 from ..backend import matmul as bmm
-from ..configs.base import ModelConfig
-from .layers import (attention, attention_param_specs, chunked_softmax_xent, scan_layers,
-                     decode_attention, embed, embed_param_specs, layer_at, logits_last,
-                     mlp, mlp_param_specs, rmsnorm, rmsnorm_spec)
+from ..configs.base import LAYER_KINDS, ModelConfig
+from .layers import (KVCacheSpec, attention, attention_param_specs, chunked_softmax_xent,
+                     decode_attention, embed, embed_param_specs, held_experts,
+                     held_experts_param_specs, layer_at, logits_last, mlp, mlp_param_specs,
+                     rmsnorm, rmsnorm_spec, route_counts, scan_layers)
 from .shardlib import ParamSpec, shard
 
 Params = Dict[str, Any]
@@ -55,7 +60,10 @@ def mamba2_param_specs(cfg: ModelConfig, layers: int) -> Params:
     dims = mamba2_dims(cfg)
     L, d = layers, cfg.d_model
     bf = jnp.bfloat16
+    bias = ({"conv_b": ParamSpec((L, dims["conv_dim"]), bf, ("layers", "tp"))}
+            if cfg.ssm_conv_bias else {})
     return {
+        **bias,
         "norm": ParamSpec((L, d), jnp.float32, ("layers", None), init="ones"),
         "in_proj": ParamSpec((L, d, dims["in_dim"]), bf,
                              ("layers", "fsdp", "tp")),
@@ -75,8 +83,9 @@ def mamba2_param_specs(cfg: ModelConfig, layers: int) -> Params:
 
 
 def _causal_conv(x: jax.Array, w: jax.Array,
-                 state: Optional[jax.Array] = None) -> jax.Array:
-    """Depthwise causal conv, kernel 4. x: (b, s, c), w: (4, c)."""
+                 state: Optional[jax.Array] = None,
+                 bias: Optional[jax.Array] = None) -> jax.Array:
+    """Depthwise causal conv, kernel 4. x: (b, s, c), w: (4, c), bias: (c,)."""
     k = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], k - 1, x.shape[2]), x.dtype)
@@ -84,6 +93,8 @@ def _causal_conv(x: jax.Array, w: jax.Array,
         pad = state.astype(x.dtype)
     xp = jnp.concatenate([pad, x], axis=1)
     out = sum(xp[:, i:i + x.shape[1]] * w[i][None, None] for i in range(k))
+    if bias is not None:
+        out = out + bias
     return jax.nn.silu(out.astype(jnp.float32)).astype(x.dtype)
 
 
@@ -109,7 +120,7 @@ def mamba2_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
     b, s, _ = x.shape
     zxbcdt = bmm(x, lp["in_proj"])
     z, xbc, dt = _split_zxbcdt(zxbcdt, dims)
-    xbc = _causal_conv(xbc, lp["conv_w"], conv_state)
+    xbc = _causal_conv(xbc, lp["conv_w"], conv_state, lp.get("conv_b"))
     xs, B, C = jnp.split(xbc, [dims["d_inner"],
                                dims["d_inner"] + dims["d_state"]], axis=-1)
     h, p, n = dims["n_heads"], dims["p"], dims["d_state"]
@@ -162,7 +173,8 @@ def mamba2_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
     y = y.reshape(b, s, dims["d_inner"])
 
     gated = y * jax.nn.silu(z.astype(jnp.float32))
-    gated = rmsnorm(gated.astype(jnp.bfloat16), lp["gate_norm"])
+    gated = rmsnorm(gated.astype(jnp.bfloat16), lp["gate_norm"],
+                    cfg.rms_norm_eps)
     out = bmm(gated, lp["out_proj"])
     if return_state:
         conv_out = jnp.concatenate(
@@ -188,6 +200,8 @@ def mamba2_step(x: jax.Array, lp: Params, cfg: ModelConfig,
     window = jnp.concatenate([conv_state.astype(xbc_new.dtype), xbc_new], axis=1)
     conv_w = lp["conv_w"]
     xbc = sum(window[:, i] * conv_w[i][None] for i in range(4))
+    if "conv_b" in lp:
+        xbc = xbc + lp["conv_b"][None]
     xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(x.dtype)     # (b, conv)
     xs, B, C = jnp.split(xbc, [dims["d_inner"],
                                dims["d_inner"] + dims["d_state"]], axis=-1)
@@ -202,7 +216,8 @@ def mamba2_step(x: jax.Array, lp: Params, cfg: ModelConfig,
     y = jnp.einsum("bn,bhnp->bhp", Cf, new_state) + lp["D"][None, :, None] * xh
     y = y.reshape(b, 1, dims["d_inner"])
     gated = y * jax.nn.silu(z.astype(jnp.float32))
-    gated = rmsnorm(gated.astype(jnp.bfloat16), lp["gate_norm"])
+    gated = rmsnorm(gated.astype(jnp.bfloat16), lp["gate_norm"],
+                    cfg.rms_norm_eps)
     out = bmm(gated, lp["out_proj"])
     return out, new_state, window[:, -3:]
 
@@ -567,3 +582,164 @@ def zamba2_decode_step(params, state, tokens, cfg):
     logits = logits_last(x, params["embedding"])
     return logits, {"ssm": flat(ssm), "conv": flat(conv).astype(jnp.bfloat16),
                     "kv": kv, "index": index + 1}
+
+
+# ===========================================================================
+# Per-layer hybrids (``layer_types``: granite-4.0-h)
+# ===========================================================================
+#
+# Each kind of block has one flat stack of weights (and of state), in layer
+# order: "mamba2" -> params["mamba"] (state "ssm", "conv"), "attention" ->
+# params["attn"] (state "kv"), "experts" -> params["moe"].  A layer is
+#     for kind in layer_types[i]:  x = x + residual_multiplier * kind(norm(x))
+
+
+_STACK = {"mamba2": "mamba", "attention": "attn", "experts": "moe"}
+
+
+def hybrid_param_tree(cfg: ModelConfig) -> Params:
+    n = {k: cfg.layer_count(k) for k in LAYER_KINDS}
+    tree = {**embed_param_specs(cfg), "final_norm": rmsnorm_spec(cfg.d_model)}
+    if n["mamba2"]:
+        tree["mamba"] = mamba2_param_specs(cfg, n["mamba2"])
+    if n["attention"]:
+        tree["attn"] = {
+            "norm": ParamSpec((n["attention"], cfg.d_model), jnp.float32,
+                              ("layers", None), init="ones"),
+            **attention_param_specs(cfg, layers=n["attention"])}
+    if n["experts"]:
+        tree["moe"] = held_experts_param_specs(cfg, n["experts"])
+    return tree
+
+
+def hybrid_state_specs(cfg: ModelConfig, batch: int, max_len: int,
+                       long_context: bool = False) -> Params:
+    """Mamba2 state of the Mamba2 layers, K/V of the attention layers."""
+    dims = mamba2_dims(cfg)
+    n = {k: cfg.layer_count(k) for k in LAYER_KINDS}
+    specs = {"index": ParamSpec((batch,), jnp.int32, ("batch",),
+                                init="zeros")}
+    if n["mamba2"]:
+        specs["ssm"] = ParamSpec(
+            (n["mamba2"], batch, dims["n_heads"], dims["d_state"], dims["p"]),
+            jnp.float32, ("layers", "batch", "tp", None, None), init="zeros")
+        specs["conv"] = ParamSpec((n["mamba2"], batch, 3, dims["conv_dim"]),
+                                  jnp.bfloat16, ("layers", "batch", None, "tp"),
+                                  init="zeros")
+    if n["attention"]:
+        specs["kv"] = KVCacheSpec(
+            n["attention"], batch, max_len, cfg.n_kv_heads, cfg.d_head,
+            seq_axis="seq_full" if long_context else "seq_tp").specs()
+    return specs
+
+
+def _walk(x: jax.Array, params: Params, cfg: ModelConfig, block,
+          carry=()) -> Tuple[jax.Array, Any]:
+    """Every layer over ``x``: one scan over the periods of ``layer_types``
+    with a period's layers unrolled in its body, each block's weights read
+    in place from its flat stack (``layer_at``; a regrouped stack is copied
+    on the chip).  ``block(kind, x, lp, i, carry)`` -> (branch, carry)
+    gives a block's residual branch, ``i`` its index in its stack, and may
+    update ``carry`` (the decode state's stacks, written in place)."""
+    types, period = cfg.layer_types, cfg.layer_period
+    per = {k: sum(k in t for t in types[:period]) for k in LAYER_KINDS}
+    r = cfg.residual_multiplier
+
+    def body(xc, p):
+        x, carry = xc
+        seen = collections.Counter()
+        for kinds in types[:period]:
+            for kind in kinds:
+                i = p * per[kind] + seen[kind]
+                seen[kind] += 1
+                y, carry = block(kind, x, layer_at(params[_STACK[kind]], i),
+                                 i, carry)
+                x = x + r * y
+        return x, carry
+
+    return scan_layers(body, (x, carry), np.arange(len(types) // period),
+                       unroll=cfg.unroll_layers)
+
+
+def _put(stack, i, value):
+    """``stack`` with layer ``i`` replaced by ``value`` (in place)."""
+    return jax.tree.map(
+        lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v.astype(a.dtype),
+                                                         i, 0), stack, value)
+
+
+def hybrid_hidden(params: Params, tokens: jax.Array,
+                  cfg: ModelConfig) -> jax.Array:
+    """Full-sequence forward: final-norm hidden states (b, s, d) over
+    ``logits_scaling`` (logits are these against the tied embedding)."""
+    eps = cfg.rms_norm_eps
+    x = embed(tokens, params) * cfg.embedding_multiplier
+
+    branches = {
+        "mamba2": lambda x, lp: mamba2_forward(rmsnorm(x, lp["norm"], eps),
+                                               lp, cfg),
+        "attention": lambda x, lp: attention(rmsnorm(x, lp["norm"], eps), lp,
+                                             cfg, causal=True),
+        "experts": lambda x, lp: held_experts(x, lp, cfg, eps)[0]}
+
+    def block(kind, x, lp, i, carry):
+        return _remat(branches[kind], cfg)(x, lp), carry
+
+    x, _ = _walk(x, params, cfg, block)
+    return rmsnorm(x, params["final_norm"], eps) / cfg.logits_scaling
+
+
+def hybrid_loss(params, batch, cfg):
+    x = hybrid_hidden(params, batch["tokens"], cfg)
+    return chunked_softmax_xent(x, params["embedding"], batch["labels"],
+                                cfg.loss_chunk, unroll=cfg.unroll_layers)
+
+
+def hybrid_decode_step(params, state, tokens, cfg):
+    """One token a row.  A state that holds ``routed`` (``ModelAPI.
+    counting_routes``) adds the step's expert routing to it."""
+    eps = cfg.rms_norm_eps
+    x = embed(tokens, params) * cfg.embedding_multiplier
+    index = state["index"]
+
+    def block(kind, x, lp, i, st):
+        if kind == "experts":
+            y, idx = held_experts(x, lp, cfg, eps)
+            if "routed" in st:
+                st = {**st, "routed": st["routed"] + route_counts(idx, cfg)}
+            return y, st
+        h = rmsnorm(x, lp["norm"], eps)
+        if kind == "mamba2":
+            y, s2, c2 = mamba2_step(h, lp, cfg,
+                                    *layer_at((st["ssm"], st["conv"]), i))
+            return y, {**st, "ssm": _put(st["ssm"], i, s2),
+                       "conv": _put(st["conv"], i, c2)}
+        y, kv = decode_attention(h, lp, cfg, layer_at(st["kv"], i), index)
+        return y, {**st, "kv": _put(st["kv"], i, kv)}
+
+    x, state = _walk(x, params, cfg, block, {**state, "index": index + 1})
+    x = rmsnorm(x, params["final_norm"], eps)
+    return logits_last(x, params["embedding"]) / cfg.logits_scaling, state
+
+
+# ===========================================================================
+# The two hybrid programs
+# ===========================================================================
+
+
+class HybridProgram(NamedTuple):
+    param_tree: Callable
+    state_specs: Callable
+    loss: Callable
+    decode_step: Callable
+
+
+ZAMBA2 = HybridProgram(zamba2_param_tree, zamba2_state_specs, zamba2_loss,
+                       zamba2_decode_step)
+PER_LAYER = HybridProgram(hybrid_param_tree, hybrid_state_specs, hybrid_loss,
+                          hybrid_decode_step)
+
+
+def hybrid_program(cfg: ModelConfig) -> HybridProgram:
+    """The program of a ``hybrid`` family config (``ModelConfig.per_layer``)."""
+    return PER_LAYER if cfg.per_layer else ZAMBA2

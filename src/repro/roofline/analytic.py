@@ -6,7 +6,7 @@ expose recompute/redundancy (spec: 'catches remat/redundancy waste').
 
 from __future__ import annotations
 
-from ..configs.base import ModelConfig, ShapeConfig
+from ..configs.base import LAYER_KINDS, ModelConfig, ShapeConfig
 from ..models.ssm import mamba2_dims, rwkv6_dims
 
 
@@ -26,6 +26,16 @@ def _moe_flops_per_tok(cfg: ModelConfig) -> float:
     active = cfg.top_k * _mlp_flops_per_tok(cfg)
     shared = _mlp_flops_per_tok(cfg) if cfg.shared_expert else 0
     return route + active + shared
+
+
+def _held_experts_flops_per_tok(cfg: ModelConfig) -> float:
+    """Router over every expert, the shared expert, and the held routed
+    experts at their uniform share of a token's ``top_k`` routes."""
+    route = 2 * cfg.d_model * cfg.n_experts
+    shared = _mlp_flops_per_tok(cfg, cfg.d_shared_expert)
+    routed = (cfg.top_k * cfg.n_experts_held / cfg.n_experts
+              * _mlp_flops_per_tok(cfg, cfg.d_expert))
+    return route + shared + routed
 
 
 def _attn_score_flops(cfg: ModelConfig, s: int, causal: bool = True,
@@ -108,6 +118,19 @@ def forward_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
 
     if f == "ssm":
         total = cfg.n_layers * toks * _rwkv_flops_per_tok(cfg)
+        total += (toks if shape.kind != "decode" else b) * \
+            _head_flops_per_tok(cfg)
+        return total
+
+    if cfg.per_layer:
+        n = {k: cfg.layer_count(k) for k in LAYER_KINDS}
+        total = toks * (n["mamba2"] * _mamba_flops_per_tok(cfg)
+                        + n["attention"] * _attn_proj_flops_per_tok(cfg)
+                        + n["experts"] * _held_experts_flops_per_tok(cfg))
+        if shape.kind == "decode":
+            total += n["attention"] * b * 2 * 2 * ctx * cfg.q_dim
+        else:
+            total += n["attention"] * b * _attn_score_flops(cfg, s)
         total += (toks if shape.kind != "decode" else b) * \
             _head_flops_per_tok(cfg)
         return total
@@ -201,6 +224,7 @@ def hbm_bytes_per_device(cfg: ModelConfig, shape: ShapeConfig,
     eff = min(s, cfg.sliding_window or s)
     kv = cfg.n_layers * b_loc * eff * cfg.kv_dim * 2 * kv_bytes_tok
     if cfg.family == "hybrid":
-        kv = (cfg.n_layers // max(cfg.shared_attn_period, 1)) * b_loc * eff \
-            * cfg.kv_dim * 2 * kv_bytes_tok
+        apps = (cfg.layer_count("attention") if cfg.per_layer
+                else cfg.n_layers // max(cfg.shared_attn_period, 1))
+        kv = apps * b_loc * eff * cfg.kv_dim * 2 * kv_bytes_tok
     return weights + kv

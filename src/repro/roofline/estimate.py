@@ -34,14 +34,16 @@ ARTIFACT_DIR = Path(__file__).resolve().parents[3] / "artifacts" / "roofline"
 def _unit(cfg) -> Dict[str, int]:
     """Layer-unit size and the config overrides for k units."""
     if cfg.family == "hybrid":
-        return {"unit_layers": cfg.shared_attn_period,
-                "units": cfg.n_layers // cfg.shared_attn_period}
+        period = cfg.layer_period if cfg.per_layer else cfg.shared_attn_period
+        return {"unit_layers": period, "units": cfg.n_layers // period}
     return {"unit_layers": 1, "units": cfg.n_layers}
 
 
 def _overrides_for_units(cfg, k: int) -> Dict[str, int]:
     u = _unit(cfg)
     ov = {"n_layers": k * u["unit_layers"], "unroll_layers": True}
+    if cfg.per_layer:
+        ov["layer_types"] = cfg.layer_types[:k * u["unit_layers"]]
     if cfg.family == "encdec":
         ov["n_enc_layers"] = k            # unit = (1 dec + 1 enc) pair
     return ov

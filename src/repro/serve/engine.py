@@ -61,6 +61,15 @@ enabled=False)`` to disable tracing while keeping the stats registry.
 ``live`` counts the slots whose request already has its first token:
 those the admission stalls.  A program lowered during a step (a new
 shape) adds a ``jit_compile`` event (``repro.obs.compiles``).
+
+A model whose layers hold experts counts the routing of the steps that
+absorb a prompt on the device (``ModelAPI.counts_routes``): each
+``prefill`` span carries ``moe_pairs`` ((token, held expert) pairs) and
+``moe_experts_hit`` ((layer, held expert) with at least one pair, summed
+over the steps), and the counters ``moe_pairs_total`` and
+``moe_experts_hit_total`` add them up, labelled ``step`` = ``absorb``.  The
+counts stay on the device until the prompt's first token is fetched.
+Batched steps count nothing.
 """
 
 from __future__ import annotations
@@ -265,6 +274,16 @@ class ServeEngine:
         reg.gauge("serve_slots", "configured decode slots").set(slots)
         self._h_queue_wait = reg.histogram(
             "serve_queue_wait_seconds", "submit to slot admission (s)")
+        self._counts_routes = self.api.counts_routes
+        if self._counts_routes:
+            self._c_moe = {
+                "moe_pairs": reg.counter(
+                    "moe_pairs_total", "(token, held expert) routing pairs",
+                    labels=("step",)),
+                "moe_experts_hit": reg.counter(
+                    "moe_experts_hit_total",
+                    "held experts routed at least one token, per layer "
+                    "and step", labels=("step",))}
         if backend is not None and hasattr(backend, "attach_obs"):
             backend.attach_obs(self.obs)   # callback latency + guard events
         if hwloop is not None and hasattr(hwloop, "attach_obs"):
@@ -360,6 +379,8 @@ class ServeEngine:
                                         max_len=self.max_len)
             return logits, sub, 1
         sub = self.api.make_decode_state(self._sub_shape)
+        if self._counts_routes:
+            sub = self.api.counting_routes(sub)
         # padded to max_len, so that the per-token slice lowers once and
         # not once per prompt length
         toks = np.zeros((1, self.max_len), np.int32)
@@ -462,11 +483,15 @@ class ServeEngine:
                 live = sum(1 for r in self.scheduler.active.values()
                            if r.out_tokens)
                 with self.obs.span("prefill", uid=req.uid, slot=slot,
-                                   prompt_len=len(req.prompt), live=live):
+                                   prompt_len=len(req.prompt),
+                                   live=live) as span:
                     logits, sub, n = self._absorb(req)
+                    routed = sub.pop("routed", None)
                     self._state = self._inject(self._state, jnp.int32(slot),
                                                sub)
                     tok = int(np.asarray(logits)[0].argmax())
+                    if routed is not None:
+                        self._count_routes(span, routed)
                 used += n
                 self.stats.prefill_steps += n
                 self.stats.admitted += 1
@@ -480,6 +505,14 @@ class ServeEngine:
                     self.scheduler.pending.appendleft(req)
                 break
         return used
+
+    def _count_routes(self, span, routed: jax.Array) -> None:
+        """Put an absorbed prompt's routing counts on its span and add them
+        to the ``moe_*_total`` counters."""
+        counts = dict(zip(self._c_moe, np.asarray(routed).tolist()))
+        span.set(**counts)
+        for k, v in counts.items():
+            self._c_moe[k].inc(v, step="absorb")
 
     def _publish_backend_step(self, tel, step_flags: List[bool]) -> None:
         """Fold one decode step's backend telemetry into the registry:

@@ -36,7 +36,8 @@ def _decode_all(api, params, toks):
 
 
 @pytest.mark.parametrize("arch", ["rwkv6-1.6b", "zamba2-2.7b", "phi4-mini-3.8b",
-                                  "llava-next-mistral-7b"])
+                                  "llava-next-mistral-7b",
+                                  "granite-4.0-h-small"])
 def test_decode_matches_parallel_forward(arch):
     """Running the prompt token-by-token through decode_step must produce the
     same last-position logits as the parallel (training) forward."""
@@ -64,6 +65,9 @@ def test_decode_matches_parallel_forward(arch):
         x, _ = jax.lax.scan(lambda c, lp: (S.rwkv6_block(c, lp, cfg), ()), x,
                             params["blocks"])
         full = logits_last(rmsnorm(x, params["final_norm"])[:, -1:],
+                           params["embedding"])
+    elif cfg.per_layer:  # per-layer hybrid: the loss path's forward
+        full = logits_last(S.hybrid_hidden(params, toks, cfg)[:, -1:],
                            params["embedding"])
     else:  # hybrid: recompute via the loss path pieces
         from repro.models.layers import chunked_softmax_xent  # noqa

@@ -92,6 +92,7 @@ EXPECTED_PARAMS_B = {
     "llama4-scout-17b-a16e": 106.7, "granite-20b": 20.0,
     "qwen1.5-110b": 110.0, "starcoder2-3b": 3.03, "phi4-mini-3.8b": 3.84,
     "seamless-m4t-medium": 0.72, "zamba2-2.7b": 2.35, "rwkv6-1.6b": 1.45,
+    "granite-4.0-h-small": 32.2, "granite-4.0-h-small-share": 4.418,
 }
 
 
@@ -117,6 +118,8 @@ def test_exact_assigned_dimensions(arch):
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
         "rwkv6-1.6b": (24, 2048, 32, 32, 7168, 65536),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
+        "granite-4.0-h-small-share": (20, 4096, 32, 8, 768, 100352),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
            cfg.vocab_size)
@@ -131,3 +134,15 @@ def test_exact_assigned_dimensions(arch):
         assert (cfg.n_experts, cfg.top_k, cfg.shared_expert) == (16, 1, True)
     if arch == "zamba2-2.7b":
         assert cfg.ssm_state == 64 and cfg.shared_attn_period == 6
+    if arch.startswith("granite-4.0-h-small"):
+        mamba, attn = ("mamba2", "experts"), ("attention", "experts")
+        period = (mamba,) * 5 + (attn,) + (mamba,) * 4
+        assert (cfg.n_experts, cfg.top_k, cfg.layer_types) == (
+            72, 10, period * (cfg.n_layers // 10))
+        assert (cfg.d_expert, cfg.d_shared_expert, cfg.ssm_state,
+                cfg.ssm_d_head, cfg.ssm_conv_bias) == (768, 1536, 128, 64, True)
+        assert (cfg.embedding_multiplier, cfg.residual_multiplier,
+                cfg.attention_multiplier, cfg.logits_scaling,
+                cfg.rms_norm_eps, cfg.position_embedding_type) == (
+            12, 0.22, 1 / 128, 16, 1e-5, "nope")
+        assert cfg.n_experts_held == (9 if arch.endswith("share") else 72)

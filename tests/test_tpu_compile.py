@@ -124,6 +124,24 @@ def test_zamba2_decode_step_reads_weights_in_place(one_chip):
             < in_proj.size * in_proj.dtype.itemsize)
 
 
+@pytest.mark.parametrize("slots", [1, 16])
+def test_granite_share_decode_step_writes_its_state_in_place(slots, one_chip):
+    """granite-4.0-h-small's one-chip share at its engine's 16 slots x 1024
+    and at batch 1: the step fits the chip, and writes each layer's state
+    into the state stacks it carries instead of collecting and re-stacking
+    them (which held 4.8 GB of temporaries at 16 slots, over the chip)."""
+    api = model_api(get_config("granite-4.0-h-small-share"))
+    params = _on(one_chip, api.param_specs())
+    state = _on(one_chip, api.decode_state_specs(
+        ShapeConfig("serve", 1024, slots, "decode")))
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(api.decode_step).lower(params, state, tokens).compile()
+    assert _fits(compiled) > 8.8e9          # the 8.84 GB of weights
+    ssm = state["ssm"]
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < ssm.size * ssm.dtype.itemsize / 4)
+
+
 @pytest.mark.parametrize("name", sorted(chip_smoke.KERNELS))
 def test_kernel_compiles_for_tpu(name, one_chip):
     fn, arg_shapes = chip_smoke.KERNELS[name]
